@@ -25,14 +25,13 @@ def main():
     journal = tempfile.NamedTemporaryFile(
         mode="w", suffix=".jsonl", prefix="decisions-", delete=False
     )
-    sys.argv = [
-        "serve", "--arch", "qwen3-32b", "--reduced",
+    serve.main([
+        "--arch", "qwen3-32b", "--reduced",
         "--requests", "8", "--batch-slots", "4",
         "--prompt-len", "24", "--cache-len", "96",
         "--max-new", "16", "--reuse",
         "--control-every", "4", "--control-journal", journal.name,
-    ]
-    serve.main()
+    ])
     print(f"replay the run's decisions from {journal.name} with "
           f"repro.control.load_journal")
 

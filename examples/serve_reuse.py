@@ -17,13 +17,12 @@ from repro.launch import serve
 
 
 def main():
-    sys.argv = [
-        "serve", "--arch", "mixtral-8x7b", "--reduced",
+    serve.main([
+        "--arch", "mixtral-8x7b", "--reduced",
         "--requests", "8", "--batch-slots", "4",
         "--prompt-len", "24", "--cache-len", "96",
         "--max-new", "12", "--reuse",
-    ]
-    serve.main()
+    ])
 
 
 if __name__ == "__main__":

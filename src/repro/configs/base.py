@@ -167,6 +167,16 @@ class ModelConfig:
         inactive = self.n_layers * (self.n_experts - self.top_k) * per_mlp
         return total - inactive
 
+    def with_layers(self, n_layers: int) -> "ModelConfig":
+        """The same model cut to its first `n_layers` layers. Only depth
+        changes: every width stays as published, so per-layer shapes, kernel
+        tiles and per-token memory traffic per layer are the real ones."""
+        if n_layers < 1 or n_layers % self.superblock_layers:
+            raise ValueError(
+                f"{self.name}: n_layers={n_layers} must be a positive "
+                f"multiple of its {self.superblock_layers}-layer superblock")
+        return dataclasses.replace(self, n_layers=n_layers)
+
     def reduced(self) -> "ModelConfig":
         """Same family, smoke-test scale. Keeps every structural feature."""
         return dataclasses.replace(

@@ -34,6 +34,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.policy import (
     MODE_BASIC,
@@ -180,6 +181,12 @@ class ReuseEngine:
     # shard_sites() BEFORE init_cache; sharded entries carry the shard axis
     # inside the layer axis ([S, ...] unstacked, [L, S, ...] stacked).
     shards: dict[str, int] = dataclasses.field(default_factory=dict)
+    # the device mesh a sharded engine runs on (set by shard_sites). With a
+    # mesh, each device evaluates only its own shard lane inside a
+    # shard_map over the "model" axis — the form a compiled Pallas kernel
+    # needs, since GSPMD cannot partition a custom call. Without one the
+    # shard lanes are vmapped on whatever devices hold them.
+    mesh: Any = None
     # interconnect accounting (bytes, cumulative): the per-window cross-mesh
     # counter reduce riding the ctrl snapshot, and sharded ctrl-lane write
     # fan-out. sensor.cost_model prices these into E_ICI energy.
@@ -225,22 +232,30 @@ class ReuseEngine:
         self.exec_cooldown[name] = 0
         return spec
 
-    def shard_sites(self, n_shards: int) -> dict[str, int]:
+    def shard_sites(self, n_shards: int, mesh=None) -> dict[str, int]:
         """Plan an N-way model-axis split of every registered site — the
         sharded-serving entry point, called BEFORE init_cache. Validates
         divisibility up front (a clear error beats a reshape failure deep in
         the traced step) and records the plan in `self.shards`; init_cache
         then expands every entry with the shard axis, apply() dispatches
-        through the vmap-over-shards path, and the ctrl snapshot collapses
-        shard lanes back out. n_shards <= 1 clears the plan (unsharded)."""
+        through the per-shard path, and the ctrl snapshot collapses shard
+        lanes back out. `mesh` (whose "model" axis is n_shards wide) makes
+        apply() run each shard on its own device. n_shards <= 1 clears the
+        plan (unsharded)."""
         from repro.dist.shard import validate_shardable
 
+        self.mesh = None
         if n_shards <= 1:
             self.shards = {}
             return self.shards
         for spec in self.sites.values():
             validate_shardable(spec, n_shards)
+        if mesh is not None and int(mesh.shape["model"]) != n_shards:
+            raise ValueError(
+                f"mesh model axis is {mesh.shape['model']} wide, not "
+                f"{n_shards}")
         self.shards = {name: n_shards for name in self.sites}
+        self.mesh = mesh
         return self.shards
 
     def init_cache(self, batch: int) -> dict[str, Any]:
@@ -316,10 +331,12 @@ class ReuseEngine:
     ) -> tuple[jax.Array, dict[str, jax.Array], ReuseStats]:
         """One sharded site call: vmap the shard-local evaluation over the
         entry's shard axis. The weight panel splits column-wise to match
-        (`w[:, s·nl:(s+1)·nl]` per shard); x is replicated in closure; every
-        cache leaf carries the shard axis uniformly, so `in_axes=0` maps the
-        whole entry. NOTHING here crosses shards — no gather, no reduce —
-        which is the hot-path invariant the HLO check pins.
+        (`w[:, s·nl:(s+1)·nl]` per shard); x is replicated; every cache leaf
+        carries the shard axis uniformly, so `in_axes=0` maps the whole
+        entry. NOTHING here crosses shards — no gather, no reduce — which is
+        the hot-path invariant the HLO check pins. On an engine with a mesh
+        the vmap runs inside a shard_map, so each device maps only the shard
+        lane it holds.
 
         kernelMode dispatch lifts OUTSIDE the vmap: `lax.cond` under vmap
         lowers to a select that executes BOTH branches on every shard, so the
@@ -335,36 +352,50 @@ class ReuseEngine:
         ws = jnp.moveaxis(w.reshape(k, n_shards, nl), 1, 0)   # [S, K, nl]
         bs = None if b is None else b.reshape(n_shards, nl)
         idx = jnp.arange(n_shards, dtype=jnp.int32)
+        if mode is None and entry.get("ctrl") is None:
+            raise ValueError(
+                f"site {name!r}: sharded mode=None needs a ctrl block "
+                "in the cache entry (engine.init_cache creates it)"
+            )
 
-        def _sharded_eval(static_mode: str):
-            def one(i, wl, bl, el):
-                shard = ShardCtx(index=i, count=n_shards,
-                                 n_total=spec.out_features,
-                                 gn_total=gn_total)
-                return reuse_linear(
-                    x, wl, bl, el, local, mode=static_mode,
-                    impl=self.impl, shard=shard,
-                )
+        def eval_lanes(x, idx, ws, bs, entry):
+            def _sharded_eval(static_mode: str):
+                def one(i, wl, bl, el):
+                    shard = ShardCtx(index=i, count=n_shards,
+                                     n_total=spec.out_features,
+                                     gn_total=gn_total)
+                    return reuse_linear(
+                        x, wl, bl, el, local, mode=static_mode,
+                        impl=self.impl, shard=shard,
+                    )
 
-            axes = (0, 0, None if b is None else 0, 0)
-            return lambda: jax.vmap(one, in_axes=axes)(idx, ws, bs, entry)
+                axes = (0, 0, None if b is None else 0, 0)
+                return lambda: jax.vmap(one, in_axes=axes)(idx, ws, bs, entry)
 
-        if mode is None:
-            ctrl = entry.get("ctrl")
-            if ctrl is None:
-                raise ValueError(
-                    f"site {name!r}: sharded mode=None needs a ctrl block "
-                    "in the cache entry (engine.init_cache creates it)"
-                )
+            if mode is not None:
+                return _sharded_eval(mode)()
             # the layer's mode lane, replicated across shards → lane 0
-            pred = jnp.reshape(ctrl["mode_id"], (-1,))[0] > 0
-            out_s, new_entry, stats_s = jax.lax.cond(
+            pred = jnp.reshape(entry["ctrl"]["mode_id"], (-1,))[0] > 0
+            return jax.lax.cond(
                 pred, _sharded_eval("reuse"), _sharded_eval("basic")
             )
-        else:
-            out_s, new_entry, stats_s = _sharded_eval(mode)()
+
+        if self.mesh is not None:
+            lanes = PartitionSpec("model")
+            eval_lanes = jax.shard_map(
+                eval_lanes, mesh=self.mesh,
+                in_specs=(PartitionSpec(), lanes, lanes, lanes, lanes),
+                out_specs=lanes, check_vma=False,
+            )
+        out_s, new_entry, stats_s = eval_lanes(x, idx, ws, bs, entry)
         # [S, *lead, nl] → [*lead, S, nl] → [*lead, N]
         out = jnp.moveaxis(out_s, 0, -2).reshape(*lead, spec.out_features)
+        if self.mesh is not None:
+            # gather the activation here, so the rest of the layer runs
+            # replicated exactly as on one device instead of inheriting the
+            # model-axis split (which would reorder its reductions)
+            out = jax.lax.with_sharding_constraint(
+                out, NamedSharding(self.mesh, PartitionSpec()))
         stats = jax.tree.map(lambda a: a[0], stats_s)  # replicated per shard
         return out, new_entry, stats
 

@@ -130,9 +130,6 @@ def cache_shape_signatures(cache: dict[str, Any]) -> set[tuple[str, tuple]]:
         sigs.add((dt, tuple(int(d) for d in leaf.shape)))
         sharding = getattr(leaf, "sharding", None)
         if sharding is not None:
-            try:
-                sigs.add((dt, tuple(
-                    int(d) for d in sharding.shard_shape(leaf.shape))))
-            except Exception:
-                pass
+            sigs.add((dt, tuple(
+                int(d) for d in sharding.shard_shape(leaf.shape))))
     return sigs

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _skip_sel(block_mask: jax.Array) -> jax.Array:
     """sel[m, k] = index of the newest non-skipped k'-block with k' <= k.
@@ -210,7 +208,8 @@ def reuse_matmul(
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((m, n), prev_out.dtype),
             interpret=interpret,
-            compiler_params=CompilerParams(
+            name="reuse_matmul_output",
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
         )(block_mask, sel, delta, w, prev_out)
@@ -253,7 +252,8 @@ def reuse_matmul(
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((m, n), prev_out.dtype),
             interpret=interpret,
-            compiler_params=CompilerParams(
+            name="reuse_matmul_input",
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             ),
         )(block_mask, sel, delta, w, prev_out)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 from repro.kernels.reuse_matmul import _skip_sel
 
 
@@ -82,7 +80,7 @@ def reuse_matmul_int8(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(block_mask, sel, delta_q, w_q, prev_acc)

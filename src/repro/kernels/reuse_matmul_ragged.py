@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _kernel(count_ref, idx_ref, delta_ref, w_ref, prev_ref, out_ref, acc_ref,
             *, n_k: int):
@@ -121,7 +119,8 @@ def reuse_matmul_ragged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), prev_out.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        name="reuse_matmul_ragged",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(counts.astype(jnp.int32), idx.astype(jnp.int32), delta, w, prev_out)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, out_ref, s_new_ref):
     r = r_ref[0].astype(jnp.float32)        # [dk]
@@ -78,7 +76,7 @@ def wkv6_decode(
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
     )(rf, kf, vf, wf, uf, sf)

@@ -6,23 +6,32 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """A mesh whose axes GSPMD partitions automatically: the serve step
+    commits its inputs' shardings and lets the compiler place the rest
+    (`jax.make_mesh` would default to explicit axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod prepends a 2-pod axis (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(n_devices: int, model_size: int | None = None):
-    """Small mocked mesh over host devices (tests/CI): ("data", "model") with
-    the model axis `model_size` wide (default: every device on the model
-    axis — the sharded-serving test shape).
+    """Mesh over the first `n_devices` devices of this host: ("data",
+    "model") with the model axis `model_size` wide (default: every device on
+    the model axis — the sharded-serving shape).
 
-    Host devices come from `XLA_FLAGS=--xla_force_host_platform_device_count=N`
-    (set BEFORE jax initializes); validate up front with actionable errors
-    instead of letting jax.make_mesh fail on an opaque reshape.
+    On a TPU host these are its chips. A CPU host has one device unless
+    `XLA_FLAGS=--xla_force_host_platform_device_count=N` is set before jax
+    initializes. Validate up front with actionable errors instead of letting
+    jax.make_mesh fail on an opaque reshape.
     """
     if n_devices < 1:
         raise ValueError(f"n_devices must be >= 1, got {n_devices}")
@@ -37,20 +46,18 @@ def make_host_mesh(n_devices: int, model_size: int | None = None):
     avail = jax.device_count()
     if avail < n_devices:
         raise RuntimeError(
-            f"mesh wants {n_devices} devices but only {avail} are visible — "
-            f"mock host devices with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n_devices} "
-            f"(must be set before jax initializes)"
+            f"mesh wants {n_devices} devices but only {avail} "
+            f"{jax.default_backend()} devices are visible (on a CPU host, "
+            f"set XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{n_devices} before jax initializes)"
         )
-    return jax.make_mesh(
-        (n_devices // model_size, model_size), ("data", "model")
-    )
+    return _auto_mesh((n_devices // model_size, model_size), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str):
     """Mesh from a CLI spec string.
 
-    "host:N"    — N mocked host devices, all on the model axis
+    "host:N"    — N devices of this host, all on the model axis
     "host:N@S"  — N host devices, model axis S wide (data axis N/S)
     "prod"      — the fixed 16x16 production pod
     "prod-pod"  — 2x16x16 multi-pod

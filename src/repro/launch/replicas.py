@@ -36,7 +36,6 @@ ROADMAP assigns the router.
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 from typing import Any
@@ -55,13 +54,14 @@ from repro.obs.metrics import (
 )
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.scheduler import ContinuousBatcher, Request, reset_slot
 from repro.serve.serve_step import (
     build_reuse_engine,
-    decode_step,
     greedy_sample,
     init_serve_state,
-    prefill_step,
+    jit_decode,
+    jit_prefill,
 )
 from repro.models import init_params
 
@@ -86,7 +86,7 @@ class Replica:
         self.spans_path = os.path.join(self.obs_dir, "spans.jsonl")
         self.metrics_path = os.path.join(self.obs_dir, "metrics.jsonl")
 
-        self.engine = build_reuse_engine(cfg, impl="jnp")
+        self.engine = build_reuse_engine(cfg, impl="pallas")
         self.registry = MetricsRegistry()
         self.journal = DecisionJournal(
             os.path.join(self.obs_dir, "journal.jsonl"))
@@ -143,13 +143,7 @@ class Replica:
         key = self._spec_signature()
         fn = self._decode_variants.get(key)
         if fn is None:
-            engine, cfg = self.engine, self.cfg
-
-            @functools.partial(jax.jit, donate_argnums=(2, 3))
-            def _step(p, toks, st, rc):
-                return decode_step(p, cfg, toks, st, engine=engine,
-                                   reuse_cache=rc)
-            self._decode_variants[key] = fn = _step
+            self._decode_variants[key] = fn = jit_decode(self.cfg, self.engine)
         return fn
 
     # --------------------------------------------------------- batcher wiring
@@ -158,14 +152,12 @@ class Replica:
 
         cfg, params = self.cfg, self.params
 
-        @jax.jit
-        def jit_prefill(p, toks, st):
-            return prefill_step(p, cfg, toks, st)
+        prefill_jit = jit_prefill(cfg)
 
         def prefill_fn(prompt, slot):
             full = jnp.zeros((args.batch_slots, prompt.shape[1]), jnp.int32)
             full = full.at[slot].set(jnp.asarray(prompt[0]))
-            logits, new_state = jit_prefill(
+            logits, new_state = prefill_jit(
                 params, full, self.sstate["state"])
             self.sstate["state"] = new_state
             self.sstate["rcache"] = reset_slot(self.sstate["rcache"], slot)
@@ -272,7 +264,7 @@ class Replica:
               f"quarantined={self.breaker.quarantined_lanes()}")
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> None:
     from repro.obs.fleet import (
         FleetAggregator,
         export_fleet_metrics,
@@ -312,7 +304,7 @@ def main() -> None:
     ap.add_argument("--slo-min-baseline", type=float, default=0.05)
     ap.add_argument("--slo-p95-target", type=float, default=None)
     ap.add_argument("--baseline-windows", type=int, default=3)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.inject_replica is not None and not args.inject:
         ap.error("--inject-replica requires --inject")
@@ -322,6 +314,7 @@ def main() -> None:
         cfg = cfg.reduced()
     assert cfg.family != "audio", "encoder archs have no decode path"
 
+    print(f"compile cache: {enable_compile_cache()}")
     obs_trace.enable()
     os.makedirs(args.out, exist_ok=True)
     params = init_params(cfg, jax.random.PRNGKey(args.seed))

@@ -3,9 +3,21 @@
     PYTHONPATH=src python -m repro.launch.serve --arch mixtral-8x7b --reduced \
         --requests 8 --batch-slots 4 --max-new 24 --reuse
 
-Runs the full serving stack at reduced scale: prefill into slot lanes, shared
-decode step with the reuse engine threaded, per-site similarity stats printed
-at the end (the live analogue of paper Fig. 12's per-layer similarity).
+Runs the full serving stack: prefill into slot lanes, shared decode step with
+the reuse engine threaded, per-site similarity stats printed at the end (the
+live analogue of paper Fig. 12's per-layer similarity). `--reduced` shrinks
+every width to smoke-test scale; `--layers N` keeps the published widths and
+cuts only the depth, which is how a large model fits one chip:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b --layers 8 \
+        --reuse --batch-slots 8 --prompt-len 128 --cache-len 2048 \
+        --check-kernels
+
+The reuse sites run on the best compiled kernel substrate of the process
+(`kernels/backend.py`): the Pallas kernels on a TPU, the compiled-XLA tier on
+a CPU. `--check-kernels` makes that a contract: the run fails unless the
+substrate is compiled Pallas, no site resolves to the reference GEMM, and the
+compiled decode step carries the delta and reuse-GEMM kernels.
 
 Observability (`repro.obs`): `--obs` turns on span tracing + metrics for the
 run; `--obs-dir OUT` additionally exports `metrics.prom` (Prometheus
@@ -43,22 +55,68 @@ import numpy as np
 from repro.obs import events, trace as obs_trace
 
 from repro.configs import get_config
-from repro.core.reuse_cache import cache_bytes
+from repro.core.reuse_cache import cache_bytes, resolve_exec_path
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.scheduler import ContinuousBatcher, Request, reset_slot
 from repro.serve.serve_step import (
     build_reuse_engine,
-    decode_step,
     greedy_sample,
     init_serve_state,
-    prefill_step,
+    jit_decode,
+    jit_prefill,
 )
 from repro.models import init_params
 
 
-def main() -> None:
+def require_pallas(engine, hlo: str) -> dict[str, int]:
+    """Fail unless the compiled decode step runs the reuse sites on compiled
+    Pallas: the substrate is Pallas, every site resolves to a kernel path
+    (never the reference GEMM of `kernels/ref.py` or the jnp gather), and the
+    HLO carries the fused delta kernel plus each site's GEMM kernel. Returns
+    the Pallas kernel call counts found in the HLO."""
+    from repro.kernels import backend
+    from repro.roofline.hlo_parse import pallas_kernel_calls
+
+    sub = backend.for_impl(engine.impl)
+    if sub is not backend.PALLAS:
+        raise RuntimeError(
+            f"reuse substrate is {sub.name!r}, not compiled Pallas "
+            f"({backend.describe()})")
+    want = {"delta_quant"}
+    for name, spec in engine.sites.items():
+        path = resolve_exec_path(spec, engine.impl)
+        if path == "kernel":
+            want.add(f"reuse_matmul_{spec.dataflow}")
+        elif path == "ragged":
+            want.add("reuse_matmul_ragged")
+        else:
+            raise RuntimeError(
+                f"site {name!r} resolves to exec_path {path!r}, which runs "
+                "no Pallas kernel")
+    calls = pallas_kernel_calls(hlo)
+    missing = sorted(want - set(calls))
+    if missing:
+        raise RuntimeError(
+            f"compiled decode step lacks Pallas kernels {missing}; "
+            f"found {calls}")
+    return calls
+
+
+def main(argv: list[str] | None = None) -> dict[str, Any]:
+    """Run the CLI on `argv` (default: the process arguments). Returns the
+    serve loop's wall seconds and, with --check-kernels, the Pallas kernel
+    counts of the compiled decode step."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve only the first N layers of the config: "
+                    "depth is cut, every width stays as published (one "
+                    "chip's share of a model that needs several)")
+    ap.add_argument("--check-kernels", action="store_true",
+                    help="compile the decode step before serving and fail "
+                    "unless its reuse sites run the compiled Pallas kernels "
+                    "(requires --reuse)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch-slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -120,9 +178,10 @@ def main() -> None:
                     "and save the final cache at exit; requires --reuse")
     ap.add_argument("--mesh", default=None, metavar="SPEC",
                     help="shard the reuse serve across a device mesh "
-                    "(repro.launch.mesh specs: 'host:N' puts N mocked host "
-                    "devices on the model axis — set XLA_FLAGS="
-                    "--xla_force_host_platform_device_count=N first — "
+                    "(repro.launch.mesh specs: 'host:N' puts N devices of "
+                    "this host on the model axis — the host's chips, or on "
+                    "a CPU host N devices forced with XLA_FLAGS="
+                    "--xla_force_host_platform_device_count=N — "
                     "'host:N@S' makes the model axis S wide, 'prod' the "
                     "16x16 pod). The reuse cache is sharded along the model "
                     "axis with the weights it shadows; skip decisions stay "
@@ -134,11 +193,11 @@ def main() -> None:
                     "(repro.guard.inject.SCENARIOS) at the production seams "
                     "— e.g. poison-nan:at_step=12,site=mlp_up — for chaos "
                     "runs; requires --reuse")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     for flag in ("sensor_jsonl", "tuned_policy", "refresh_every", "affinity",
                  "control_every", "control_journal", "cache_ckpt", "inject",
-                 "mesh"):
+                 "mesh", "check_kernels"):
         if getattr(args, flag) and not args.reuse:
             ap.error(f"--{flag.replace('_', '-')} requires --reuse")
     if args.control_journal and not args.control_every:
@@ -149,6 +208,7 @@ def main() -> None:
         print("--control-every supersedes --refresh-every "
               "(the controller runs the mode refresh itself)")
         args.refresh_every = 0
+    print(f"compile cache: {enable_compile_cache()}")
 
     obs_on = args.obs or bool(args.obs_dir)
     registry = None
@@ -177,15 +237,35 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers is not None:
+        print(f"config: {cfg.name} cut from {cfg.n_layers} to {args.layers} "
+              f"layers; widths unchanged")
+        cfg = cfg.with_layers(args.layers)
     assert cfg.family != "audio", "encoder archs have no decode path"
 
     rng = np.random.default_rng(args.seed)
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
-    state = init_serve_state(cfg, args.batch_slots, args.cache_len)
+    mesh = None
+    if args.mesh:
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.launch.mesh import parse_mesh_spec
+
+        mesh = parse_mesh_spec(args.mesh)
+        # params and decode state replicate (GSPMD partitions the step
+        # around the committed input shardings); params are drawn on the
+        # mesh directly, so no device ever holds a second copy
+        replicated = NamedSharding(mesh, PartitionSpec())
+        params = jax.jit(init_params, static_argnums=0,
+                         out_shardings=replicated)(
+            cfg, jax.random.PRNGKey(args.seed))
+        state = jax.device_put(
+            init_serve_state(cfg, args.batch_slots, args.cache_len),
+            replicated)
+    else:
+        params = init_params(cfg, jax.random.PRNGKey(args.seed))
+        state = init_serve_state(cfg, args.batch_slots, args.cache_len)
 
     engine = None
     rcache = None
-    mesh = None
     if args.reuse:
         policy = None
         if args.tuned_policy:
@@ -194,28 +274,21 @@ def main() -> None:
             policy = load_tuned_policy(args.tuned_policy)
             print(f"tuned policy: {len(policy.site_tunables)} site entries "
                   f"from {args.tuned_policy}")
-        engine = build_reuse_engine(cfg, impl="jnp", policy=policy)
-        if args.mesh:
-            from repro.launch.mesh import mesh_axes, parse_mesh_spec
+        engine = build_reuse_engine(cfg, impl="pallas", policy=policy)
+        if mesh is not None:
+            from repro.launch.mesh import mesh_axes
 
-            mesh = parse_mesh_spec(args.mesh)
             ax = mesh_axes(mesh)
-            planned = engine.shard_sites(ax["model_size"])
+            planned = engine.shard_sites(ax["model_size"], mesh=mesh)
             print(f"mesh: {dict(mesh.shape)} — {len(planned)} sites sharded "
                   f"{ax['model_size']}-way on the model axis")
         rcache = engine.init_cache(args.batch_slots)
         if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
             from repro.dist.shard import cache_shardings
 
-            # cache shards live WITH the weight columns they shadow; params
-            # and decode state replicate (GSPMD partitions the step around
-            # the committed input shardings)
+            # cache shards live WITH the weight columns they shadow
             rcache = jax.device_put(
                 rcache, cache_shardings(engine, mesh, rcache))
-            replicated = NamedSharding(mesh, PartitionSpec())
-            params = jax.device_put(params, replicated)
-            state = jax.device_put(state, replicated)
         from repro.kernels import backend as kernel_backend
 
         print(f"kernel substrate: {kernel_backend.describe()}")
@@ -265,11 +338,7 @@ def main() -> None:
     # Batched-prefill simplification: slot prefill re-runs the batch prefill
     # with the slot's prompt in its lane (a production server runs a separate
     # prefill worker; the KV-lane insertion is what matters here).
-    pending_prompts = {}
-
-    @jax.jit
-    def jit_prefill(p, toks, st):
-        return prefill_step(p, cfg, toks, st)
+    prefill_jit = jit_prefill(cfg)
 
     # Jitted decode-step variants, keyed by the registered sites' full spec
     # signature (exec paths, budgets, tile geometry — everything the closure
@@ -290,33 +359,34 @@ def main() -> None:
         key = spec_signature()
         fn = decode_variants.get(key)
         if fn is None:
-            @functools.partial(jax.jit, donate_argnums=(2, 3))
-            def _step(p, toks, st, rc):
-                return decode_step(p, cfg, toks, st, engine=engine,
-                                   reuse_cache=rc)
-            decode_variants[key] = fn = _step
+            decode_variants[key] = fn = jit_decode(cfg, engine)
         return fn
 
     decode_jit = jit_decode_factory()
 
-    if mesh is not None:
-        # The sharded-serving hot-path invariant, proven on the COMPILED
-        # artifact: no all-gather/all-to-all in the donated serve step may
-        # touch a reuse-cache buffer (shard-local quantize→delta→mask→skip;
-        # the once-per-window counter all-reduce rides the ctrl snapshot,
-        # not this step). Checked once at startup against the post-SPMD HLO.
-        from repro.dist.shard import cache_shape_signatures
-        from repro.roofline.hlo_parse import (
-            cache_collective_violations,
-            parse_collective_bytes,
-        )
-
+    kernel_calls = None
+    if mesh is not None or args.check_kernels:
+        # Checked once at startup against the compiled (post-SPMD) step.
         aval = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=a.sharding))
         tok_aval = jax.ShapeDtypeStruct((args.batch_slots, 1), jnp.int32)
         hlo = decode_jit.lower(
             aval(params), tok_aval, aval(state), aval(rcache)
         ).compile().as_text()
+    if args.check_kernels:
+        kernel_calls = require_pallas(engine, hlo)
+        print(f"kernel check: OK — decode step runs Pallas {kernel_calls}")
+    if mesh is not None:
+        # The sharded-serving hot-path invariant: no all-gather/all-to-all in
+        # the donated serve step may touch a reuse-cache buffer (shard-local
+        # quantize→delta→mask→skip; the once-per-window counter all-reduce
+        # rides the ctrl snapshot, not this step).
+        from repro.dist.shard import cache_shape_signatures
+        from repro.roofline.hlo_parse import (
+            cache_collective_violations,
+            parse_collective_bytes,
+        )
+
         violations = cache_collective_violations(
             hlo, cache_shape_signatures(rcache))
         if violations:
@@ -395,7 +465,7 @@ def main() -> None:
         nonlocal sstate
         full = jnp.zeros((args.batch_slots, prompt.shape[1]), jnp.int32)
         full = full.at[slot].set(jnp.asarray(prompt[0]))
-        logits, new_state = jit_prefill(params, full, sstate["state"])
+        logits, new_state = prefill_jit(params, full, sstate["state"])
         # only this slot's lanes changed meaningfully; adopt the new caches.
         # No admission= here: the scheduler's on_place hook has ALREADY bound
         # the slot to the incoming session (admission order: pick slot ->
@@ -578,9 +648,7 @@ def main() -> None:
     done = batcher.run()
     dt = obs_trace.now() - t0
     if args.profile_dir:
-        prof = obs_trace.stop_profile()
-        if prof:
-            print(f"device trace written to {prof}")
+        print(f"device trace written to {obs_trace.stop_profile()}")
     print(f"served {len(done)}/{args.requests} requests in {dt:.2f}s; "
           f"{batcher.stats}")
     report = None
@@ -667,6 +735,7 @@ def main() -> None:
         print(f"obs exports -> {args.obs_dir} (metrics.prom {n} lines, "
               f"metrics.jsonl, spans.jsonl {n_spans} spans)")
     assert len(done) == args.requests
+    return {"seconds": dt, "kernel_calls": kernel_calls}
 
 
 if __name__ == "__main__":

@@ -49,7 +49,11 @@ from repro.models.layers import (
 # --------------------------------------------------------------------- params
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key) -> Params:
+    """Random parameters for `cfg`, built in ONE compiled program: each f32
+    draw is fused with its bf16 cast, so peak memory stays near the bf16
+    footprint instead of holding an f32 copy of every weight."""
     keys = jax.random.split(key, cfg.n_superblocks + 4)
     p: Params = {}
 

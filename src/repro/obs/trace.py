@@ -169,33 +169,26 @@ def span(name: str, **tags: Any):
 _PROFILE: dict[str, Any] = {"dir": None}
 
 
-def start_profile(log_dir: str) -> bool:
+def start_profile(log_dir: str) -> None:
     """Open a `jax.profiler.trace` window writing to `log_dir`. Host spans
     emitted inside the window line up with the device trace through their
-    TraceAnnotations. Returns False when the profiler backend is unavailable
-    (the serve run proceeds unprofiled rather than dying)."""
+    TraceAnnotations. A profiler that cannot start raises: a run that asked
+    for a trace must not finish without one."""
     import jax
 
-    try:
-        jax.profiler.start_trace(log_dir)
-    except Exception as e:
-        print(f"obs: jax profiler unavailable ({e}); continuing unprofiled")
-        return False
+    jax.profiler.start_trace(log_dir)
     _PROFILE["dir"] = log_dir
-    return True
 
 
-def stop_profile() -> str | None:
-    """Close the open profiler window, returning its directory (or None)."""
+def stop_profile() -> str:
+    """Close the open profiler window and return its directory. Raises when
+    no window is open or the profiler fails to write the trace."""
     log_dir, _PROFILE["dir"] = _PROFILE["dir"], None
     if log_dir is None:
-        return None
+        raise RuntimeError("stop_profile: no profiler window is open")
     import jax
 
-    try:
-        jax.profiler.stop_trace()
-    except Exception as e:
-        print(f"obs: stopping jax profiler failed ({e})")
+    jax.profiler.stop_trace()
     return log_dir
 
 

@@ -12,8 +12,9 @@ collective-permute (+ their -start/-done async forms, deduped by id).
 
 from __future__ import annotations
 
+import math
 import re
-from collections import defaultdict
+from collections import Counter, defaultdict
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -26,42 +27,61 @@ _COLLECTIVES = (
     "collective-permute",
 )
 
-# e.g.:  %all-reduce.42 = f32[16,1024]{1,0} all-reduce(...)
+# One collective instruction per line, e.g.
+#   %all-reduce.42 = f32[16,1024]{1,0} all-reduce(...)
+#   %all-gather-start = (bf16[8,128]{1,0:T(8,128)(2,1)}, bf16[32,128]{...})
+#       all-gather-start(...)
+# The result shape is everything between "=" and the op name: a plain shape,
+# or a tuple whose TPU layouts carry their own parentheses, so it is taken
+# as a whole and its array shapes are read out with _ARRAY_RE.
 _OP_RE = re.compile(
-    r"%?([\w.\-]+)\s*=\s*(?:\(([^)]*)\)|(\w+)\[([\d,]*)\][^ ]*)\s+"
-    r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start)?\("
+    r"%?([\w.\-]+)\s*=\s*(.+?)\s+"
+    r"(" + "|".join(_COLLECTIVES) + r")"
+    r"(-start)?\("
 )
 
-_TUPLE_ELEM_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+_ARRAY_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+
+# Async starts whose result tuple is (operands, results[, contexts]); an
+# all-reduce-start returns its results directly.
+_OPERAND_TUPLE_STARTS = ("all-gather", "collective-permute")
 
 
-def _shape_bytes(dtype: str, dims: str) -> int:
-    nbytes = _DTYPE_BYTES.get(dtype)
-    if nbytes is None:
-        return 0
-    n = 1
-    for d in dims.split(","):
-        if d:
-            n *= int(d)
-    return n * nbytes
+def _result_shapes(
+    shape_text: str, kind: str, is_start: bool
+) -> list[tuple[str, tuple[int, ...]]]:
+    """(dtype, dims) of a collective's result arrays."""
+    if (is_start and kind in _OPERAND_TUPLE_STARTS
+            and shape_text.startswith("(")):
+        parts = _top_level_parts(shape_text[1:-1])
+        if len(parts) >= 2:
+            shape_text = parts[1]
+    return [(dt, tuple(int(d) for d in dims.split(",") if d))
+            for dt, dims in _ARRAY_RE.findall(shape_text)]
+
+
+def _top_level_parts(text: str) -> list[str]:
+    """Split a tuple body on the commas that are not inside (), [] or {}."""
+    parts, depth, start = [], 0, 0
+    for i, ch in enumerate(text):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == "," and depth == 0:
+            parts.append(text[start:i])
+            start = i + 1
+    parts.append(text[start:])
+    return parts
 
 
 def parse_collective_bytes(hlo_text: str) -> dict:
     """Returns {"total_bytes": int, "by_kind": {kind: bytes}, "count": int}."""
     by_kind: dict[str, int] = defaultdict(int)
     count = 0
-    for m in _OP_RE.finditer(hlo_text):
-        name, tuple_body, dtype, dims, kind = m.groups()
-        if name.endswith(".clone") or "-done" in name:
-            continue
-        if tuple_body is not None:
-            sz = sum(
-                _shape_bytes(dt, dm) for dt, dm in _TUPLE_ELEM_RE.findall(tuple_body)
-            )
-        else:
-            sz = _shape_bytes(dtype, dims)
-        by_kind[kind] += sz
+    for _, kind, shapes in iter_collectives(hlo_text):
+        by_kind[kind] += sum(_DTYPE_BYTES.get(dt, 0) * math.prod(dims)
+                             for dt, dims in shapes)
         count += 1
     return {
         "total_bytes": int(sum(by_kind.values())),
@@ -84,18 +104,31 @@ def iter_collectives(hlo_text: str):
     all-gather's result is the gathered — global — extent along its axis).
     Async -start/-done pairs dedupe to the -start op.
     """
-    for m in _OP_RE.finditer(hlo_text):
-        name, tuple_body, dtype, dims, kind = m.groups()
+    for line in hlo_text.splitlines():
+        m = _OP_RE.search(line)
+        if m is None:
+            continue
+        name, shape_text, kind, start = m.groups()
         if name.endswith(".clone") or "-done" in name:
             continue
-        if tuple_body is not None:
-            shapes = [
-                (dt, tuple(int(d) for d in dm.split(",") if d))
-                for dt, dm in _TUPLE_ELEM_RE.findall(tuple_body)
-            ]
-        else:
-            shapes = [(dtype, tuple(int(d) for d in dims.split(",") if d))]
-        yield name, kind, shapes
+        yield name, kind, _result_shapes(shape_text.strip(), kind, bool(start))
+
+
+# A Pallas kernel lowers to a `tpu_custom_call` whose op_name metadata ends
+# in "<kernel name>/pallas_call" (the `name=` given to pl.pallas_call).
+_PALLAS_NAME_RE = re.compile(r'op_name="[^"]*?([\w.\-]+)/pallas_call"')
+
+
+def pallas_kernel_calls(hlo_text: str) -> dict[str, int]:
+    """Count the compiled Pallas kernels in TPU HLO text, by kernel name.
+    Empty on a backend that ran no Pallas kernel."""
+    calls: Counter = Counter()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _PALLAS_NAME_RE.search(line)
+        calls[m.group(1) if m else "<unnamed>"] += 1
+    return dict(calls)
 
 
 def cache_collective_violations(

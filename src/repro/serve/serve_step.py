@@ -11,6 +11,7 @@ long_500k cells.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -123,6 +124,26 @@ def decode_step(
     )
     logits = output_logits(params, cfg, h)
     return logits, new_state, new_rcache
+
+
+def jit_prefill(cfg: ModelConfig):
+    """The compiled serving prefill: `(params, tokens, state) -> (logits,
+    state)`."""
+    return jax.jit(lambda p, toks, st: prefill_step(p, cfg, toks, st))
+
+
+def jit_decode(cfg: ModelConfig, engine: ReuseEngine | None):
+    """The compiled serving decode step: `(params, tokens, state, rcache) ->
+    (logits, state, rcache)` with the serving state and the reuse cache
+    DONATED, so XLA writes the new caches in place instead of allocating a
+    copy per token. The engine's sites are baked into the trace; rebuild
+    after a spec change."""
+
+    @functools.partial(jax.jit, donate_argnums=(2, 3))
+    def step(p, toks, st, rc):
+        return decode_step(p, cfg, toks, st, engine=engine, reuse_cache=rc)
+
+    return step
 
 
 def greedy_sample(logits: jax.Array) -> jax.Array:

@@ -62,6 +62,42 @@ def test_for_impl_mapping():
         backend.for_impl("mystery")
 
 
+def test_check_kernels_refuses_a_host_without_compiled_pallas():
+    """`serve --check-kernels` has no fallback: on a host whose "pallas"
+    impl resolves to the compiled-XLA tier the check fails before it reads
+    any HLO."""
+    from repro.configs import get_config
+    from repro.launch.serve import require_pallas
+    from repro.serve.serve_step import build_reuse_engine
+
+    engine = build_reuse_engine(get_config("qwen3-32b").reduced(),
+                                impl="pallas")
+    if backend.for_impl("pallas") is backend.PALLAS:
+        pytest.skip("this host compiles Pallas")
+    with pytest.raises(RuntimeError, match="not compiled Pallas"):
+        require_pallas(engine, "")
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    launchers cache in `.jax_cache/` at the checkout root."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        default = compile_cache.DEFAULT_DIR
+        assert compile_cache.enable_compile_cache() == str(default)
+        assert jax.config.jax_compilation_cache_dir == str(default)
+        assert default.name == ".jax_cache"
+        assert (default.parent / "pyproject.toml").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_interpret_arg_threading():
     # the one explicit value reuse_linear threads into every kernel wrapper
     assert _interpret_arg("pallas_interpret") is True

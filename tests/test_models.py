@@ -141,3 +141,22 @@ def test_param_count_formulas():
         predicted = cfg.param_count()
         assert 0.5 < actual / predicted < 2.0, (
             arch, actual, predicted)
+
+
+def test_with_layers_cuts_depth_only():
+    """`serve --layers N`: the published config with only n_layers replaced,
+    so every width, and with it every kernel shape, stays the real one."""
+    import dataclasses
+
+    cfg = ARCHS["qwen3-32b"]
+    cut = cfg.with_layers(8)
+    assert cut.n_layers == 8 and cut.n_superblocks == 8
+    assert dataclasses.replace(cut, n_layers=cfg.n_layers) == cfg
+    for bad in (0, -8):
+        with pytest.raises(ValueError, match="multiple"):
+            cfg.with_layers(bad)
+    local_global = next(c for c in ARCHS.values()
+                        if c.superblock_layers > 1)
+    with pytest.raises(ValueError, match="superblock"):
+        local_global.with_layers(local_global.superblock_layers + 1)
+

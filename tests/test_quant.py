@@ -3,10 +3,7 @@ measurements stand on."""
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.quant import (
     QuantSpec,

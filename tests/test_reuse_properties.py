@@ -9,10 +9,7 @@ can never change model outputs, only costs.
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ReuseEngine, block_zero_mask, delta_encode_int8
 from repro.core.delta import compact_block_indices

@@ -413,3 +413,43 @@ def test_mesh_placed_step_parity_and_no_gather():
     base = jax.device_get(entry_1["sensor"])
     for key in collapsed:
         assert np.array_equal(np.asarray(base[key]), collapsed[key]), key
+
+
+# Collective lines recorded from the sharded qwen3-32b decode step compiled
+# for a described v5e:2x2 mesh (backend_config trimmed). TPU layouts carry
+# parentheses (`T(8,128)(2,1)`), and an async collective-permute returns the
+# tuple (operand, result, contexts).
+_TPU_COLLECTIVES_HLO = r"""
+  %all-reduce.9 = f32[8]{0:T(128)S(1)} all-reduce(%fusion.220), channel_id=1, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%region_1.0.clone, metadata={op_name="jit(<lambda>)/while/body/closed_call/reduce_sum" stack_frame_id=18}
+  %all-gather.43 = bf16[8,1,5120]{2,0,1:T(8,128)(2,1)S(1)} all-gather(%fusion.221), channel_id=2, replica_groups=[1,4]<=[4], dimensions={2}, use_global_device_ids=true, frontend_attributes={async_collective_name="all-gather-start"}, metadata={op_name="jit(<lambda>)/while/body/closed_call/convert_element_type" stack_frame_id=23}, backend_config={"collective_algorithm_config":{"emitter":"2DAllGatherOnMajorDim","debug":"\ngroup_size = 4 \nshard_size = 20480 bytes "}}
+  %collective-permute-start.6 = (bf16[8,1,1536]{2,0,1:T(8,128)(2,1)S(1)}, bf16[8,1,1536]{2,0,1:T(8,128)(2,1)S(1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%slice.154), channel_id=9, source_target_pairs={{0,1},{1,2},{2,3}}, metadata={op_name="jit(<lambda>)/while/body/closed_call/split" stack_frame_id=84}
+  %collective-permute-done.6 = bf16[8,1,1536]{2,0,1:T(8,128)(2,1)S(1)} collective-permute-done(%collective-permute-start.6), metadata={op_name="jit(<lambda>)/while/body/closed_call/split" stack_frame_id=84}
+"""
+
+
+def test_hlo_parse_reads_tpu_collectives():
+    from repro.roofline.hlo_parse import (
+        cache_collective_violations,
+        iter_collectives,
+        parse_collective_bytes,
+    )
+
+    assert list(iter_collectives(_TPU_COLLECTIVES_HLO)) == [
+        ("all-reduce.9", "all-reduce", [("f32", (8,))]),
+        ("all-gather.43", "all-gather", [("bf16", (8, 1, 5120))]),
+        ("collective-permute-start.6", "collective-permute",
+         [("bf16", (8, 1, 1536))]),
+    ]
+    assert parse_collective_bytes(_TPU_COLLECTIVES_HLO) == {
+        "total_bytes": 32 + 8 * 5120 * 2 + 8 * 1536 * 2,
+        "by_kind": {"all-reduce": 32, "all-gather": 8 * 5120 * 2,
+                    "collective-permute": 8 * 1536 * 2},
+        "count": 3,
+    }
+    # a gather whose result has a cache leaf's shape is a violation; the
+    # permute moves the same shape but is not a gather
+    sigs = {("bf16", (8, 1, 5120)), ("bf16", (8, 1, 1536))}
+    assert cache_collective_violations(_TPU_COLLECTIVES_HLO, sigs) == [
+        {"op": "all-gather.43", "kind": "all-gather", "dtype": "bf16",
+         "dims": (8, 1, 5120)},
+    ]

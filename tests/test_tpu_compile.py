@@ -1,0 +1,127 @@
+"""Compile-only checks of the served reuse kernels for a described TPU v5e.
+
+Nothing here runs on a chip: each kernel is lowered and compiled by the TPU
+compiler for a `v5e:2x2` topology described in-process, at the real site
+shapes of qwen3-32b (the configuration `chip_smoke.py` serves). Interpret
+mode accepts block shapes and memory spaces the chip's compiler refuses, so
+these tests are the CPU-side guard that the served step's kernels still
+compile for the chip. Each result must carry the kernel as a
+`tpu_custom_call` under its own name.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this module.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.delta_quant import delta_quant
+from repro.kernels.reuse_matmul import reuse_matmul
+from repro.kernels.reuse_matmul_ragged import reuse_matmul_ragged
+from repro.models import init_params
+from repro.roofline.hlo_parse import pallas_kernel_calls
+from repro.serve.serve_step import build_reuse_engine
+
+# attn_qkv is output-stationary (K=5120 -> N=10240); mlp_out is the one
+# input-stationary site (K=25600 > 4·N=20480).
+SITES = ("attn_qkv", "mlp_out")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compile cache off: an entry
+    compiled for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def specs():
+    return build_reuse_engine(get_config("qwen3-32b"), impl="pallas").sites
+
+
+def _compile(fn, sharding, *shapes) -> str:
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("site,m", [("attn_qkv", 8), ("mlp_out", 8),
+                                    ("attn_qkv", 16)])
+def test_delta_quant_compiles(one_chip, specs, site, m):
+    spec = specs[site]
+    k = spec.in_features
+
+    def fn(x, prev_q, scale):
+        return delta_quant(x, prev_q, scale, block_m=spec.block_m,
+                           block_k=spec.block_k)
+
+    hlo = _compile(fn, one_chip, ((m, k), jnp.bfloat16), ((m, k), jnp.int8),
+                   ((), jnp.float32))
+    assert pallas_kernel_calls(hlo) == {"delta_quant": 1}
+
+
+@pytest.mark.parametrize("dataflow", ["output", "input"])
+@pytest.mark.parametrize("site", SITES)
+def test_reuse_matmul_compiles(one_chip, specs, site, dataflow):
+    spec = specs[site]
+    m, k, n = 8, spec.in_features, spec.out_features
+    gm, gk = m // spec.block_m, k // spec.block_k
+
+    def fn(delta, w, prev_out, mask):
+        return reuse_matmul(delta, w, prev_out, mask, block_m=spec.block_m,
+                            block_n=spec.block_n, block_k=spec.block_k,
+                            dataflow=dataflow)
+
+    hlo = _compile(fn, one_chip, ((m, k), jnp.bfloat16),
+                   ((k, n), jnp.bfloat16), ((m, n), jnp.float32),
+                   ((gm, gk), jnp.int32))
+    assert pallas_kernel_calls(hlo) == {f"reuse_matmul_{dataflow}": 1}
+
+
+@pytest.mark.parametrize("site", SITES)
+def test_reuse_matmul_ragged_compiles(one_chip, specs, site):
+    spec = specs[site]
+    m, k, n = 8, spec.in_features, spec.out_features
+    gm, kb = m // spec.block_m, k // spec.block_k // 2  # half-extent budget
+
+    def fn(delta, w, prev_out, counts, idx):
+        return reuse_matmul_ragged(delta, w, prev_out, counts, idx,
+                                   block_m=spec.block_m, block_n=spec.block_n,
+                                   block_k=spec.block_k)
+
+    hlo = _compile(fn, one_chip, ((m, k), jnp.bfloat16),
+                   ((k, n), jnp.bfloat16), ((m, n), jnp.float32),
+                   ((gm,), jnp.int32), ((gm, kb), jnp.int32))
+    assert pallas_kernel_calls(hlo) == {"reuse_matmul_ragged": 1}
+
+
+def test_init_params_fuses_f32_draws(one_chip):
+    """Each weight is drawn in f32 and cast to bf16 inside one compiled
+    program, so the chip never holds an f32 copy of a weight: temporaries
+    stay a small fraction of the bf16 parameters."""
+    cfg = get_config("qwen3-32b").with_layers(1)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    mem = jax.jit(init_params, static_argnums=0, out_shardings=one_chip).lower(
+        cfg, key).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1 * mem.output_size_in_bytes

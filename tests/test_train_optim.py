@@ -6,10 +6,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import ARCHS
 from repro.data.pipeline import SyntheticLMSource
