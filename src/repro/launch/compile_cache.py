@@ -27,7 +27,13 @@ def enable_compile_cache() -> str:
 
     `JAX_COMPILATION_CACHE_DIR`, when set, is JAX's own setting and wins
     untouched; otherwise the cache lives in `.jax_cache/` at the checkout
-    root. The path is part of every entry's key, so it must not move."""
+    root. The path is part of every entry's key, so it must not move.
+
+    Each key also holds the program's metadata: JAX leaves it out by
+    default, and an executable loaded from the cache would then carry the
+    op names (`jax.named_scope`) of whichever source compiled it first, so
+    a device trace would attribute its ops by stale scopes."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -342,93 +342,97 @@ def attention_forward(
     site_prefix: str = "attn",
 ) -> tuple[jax.Array, dict | None]:
     b, s, _ = x.shape
-    h = apply_norm(p["norm"], x, cfg.norm_eps)
+    # "attention" holds all but the q/k/v and output projections, which
+    # carry their reuse site's scope in decode
+    with jax.named_scope("attention"):
+        h = apply_norm(p["norm"], x, cfg.norm_eps)
     qkv = _maybe_reuse_matmul(
         f"{site_prefix}_qkv", h, p["wqkv"], p.get("bqkv"), reuse_ctx
     )
-    q, k, v = _split_qkv(cfg, qkv)
+    with jax.named_scope("attention"):
+        q, k, v = _split_qkv(cfg, qkv)
 
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"]["scale"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"]["scale"], cfg.norm_eps)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"]["scale"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"]["scale"], cfg.norm_eps)
 
-    if cfg.rope == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    elif cfg.rope == "mrope":
-        q = apply_mrope(q, positions, cfg.rope_theta, _mrope_sections(cfg))
-        k = apply_mrope(k, positions, cfg.rope_theta, _mrope_sections(cfg))
+        if cfg.rope == "rope":
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        elif cfg.rope == "mrope":
+            q = apply_mrope(q, positions, cfg.rope_theta, _mrope_sections(cfg))
+            k = apply_mrope(k, positions, cfg.rope_theta, _mrope_sections(cfg))
 
-    def to_cache(t):
-        """Cache layout transform: duplicate KV heads to kv_heads_eff (so the
-        cache head dim shards across TP) and optionally quantize to int8."""
-        if cfg.kv_heads_eff != cfg.n_kv_heads:
-            assert cfg.kv_heads_eff % cfg.n_kv_heads == 0
-            t = jnp.repeat(t, cfg.kv_heads_eff // cfg.n_kv_heads, axis=2)
-        if cfg.kv_cache_quant:
-            t = jnp.clip(
-                jnp.round(t.astype(jnp.float32) / cfg.kv_quant_scale),
-                -127, 127,
-            ).astype(jnp.int8)
-        return t
+        def to_cache(t):
+            """Cache layout transform: duplicate KV heads to kv_heads_eff (so the
+            cache head dim shards across TP) and optionally quantize to int8."""
+            if cfg.kv_heads_eff != cfg.n_kv_heads:
+                assert cfg.kv_heads_eff % cfg.n_kv_heads == 0
+                t = jnp.repeat(t, cfg.kv_heads_eff // cfg.n_kv_heads, axis=2)
+            if cfg.kv_cache_quant:
+                t = jnp.clip(
+                    jnp.round(t.astype(jnp.float32) / cfg.kv_quant_scale),
+                    -127, 127,
+                ).astype(jnp.int8)
+            return t
 
-    def from_cache(t):
-        if cfg.kv_cache_quant:
-            return (t.astype(jnp.float32) * cfg.kv_quant_scale).astype(x.dtype)
-        return t
+        def from_cache(t):
+            if cfg.kv_cache_quant:
+                return (t.astype(jnp.float32) * cfg.kv_quant_scale).astype(x.dtype)
+            return t
 
-    new_cache = None
-    if kv_cache is None:
-        out = blockwise_attention(
-            q, k, v,
-            causal=cfg.causal,
-            window=layer_window,
-            chunk_q=cfg.attn_chunk_q,
-            chunk_kv=cfg.attn_chunk_kv,
-        )
-    elif s > 1:
-        # Prefill into a fresh cache: blockwise attention over the new
-        # sequence, then write K/V into the cache (rolling layout for windowed
-        # layers: token t lives at slot t % cache_len, matching decode).
-        cache_len = kv_cache["k"].shape[1]
-        out = blockwise_attention(
-            q, k, v,
-            causal=cfg.causal,
-            window=layer_window,
-            chunk_q=cfg.attn_chunk_q,
-            chunk_kv=cfg.attn_chunk_kv,
-        )
-        kq, vq = to_cache(k), to_cache(v)
-        rolling = layer_window is not None and layer_window <= cache_len
-        if rolling and s >= cache_len:
-            slots = jnp.arange(s - cache_len, s) % cache_len
-            kc = kv_cache["k"].at[:, slots].set(kq[:, s - cache_len:])
-            vc = kv_cache["v"].at[:, slots].set(vq[:, s - cache_len:])
+        new_cache = None
+        if kv_cache is None:
+            out = blockwise_attention(
+                q, k, v,
+                causal=cfg.causal,
+                window=layer_window,
+                chunk_q=cfg.attn_chunk_q,
+                chunk_kv=cfg.attn_chunk_kv,
+            )
+        elif s > 1:
+            # Prefill into a fresh cache: blockwise attention over the new
+            # sequence, then write K/V into the cache (rolling layout for windowed
+            # layers: token t lives at slot t % cache_len, matching decode).
+            cache_len = kv_cache["k"].shape[1]
+            out = blockwise_attention(
+                q, k, v,
+                causal=cfg.causal,
+                window=layer_window,
+                chunk_q=cfg.attn_chunk_q,
+                chunk_kv=cfg.attn_chunk_kv,
+            )
+            kq, vq = to_cache(k), to_cache(v)
+            rolling = layer_window is not None and layer_window <= cache_len
+            if rolling and s >= cache_len:
+                slots = jnp.arange(s - cache_len, s) % cache_len
+                kc = kv_cache["k"].at[:, slots].set(kq[:, s - cache_len:])
+                vc = kv_cache["v"].at[:, slots].set(vq[:, s - cache_len:])
+            else:
+                n = min(s, cache_len)
+                kc = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], kq[:, :n], 0, 1)
+                vc = jax.lax.dynamic_update_slice_in_dim(kv_cache["v"], vq[:, :n], 0, 1)
+            new_cache = {"k": kc, "v": vc}
         else:
-            n = min(s, cache_len)
-            kc = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], kq[:, :n], 0, 1)
-            vc = jax.lax.dynamic_update_slice_in_dim(kv_cache["v"], vq[:, :n], 0, 1)
-        new_cache = {"k": kc, "v": vc}
-    else:
-        # Decode: insert the new token. Windowed layers use a rolling cache of
-        # size `window` (slot = len % cache_len); since softmax over the valid
-        # set is order-independent and RoPE is applied pre-cache with absolute
-        # positions, no extra window masking is needed — the cache only ever
-        # holds the last `window` tokens.
-        cache_len = kv_cache["k"].shape[1]
-        length = kv_len
-        if layer_window is not None and layer_window <= cache_len:
-            slot = length % cache_len
-        else:
-            slot = jnp.minimum(length, cache_len - 1)
-        kc = jax.lax.dynamic_update_index_in_dim(
-            kv_cache["k"], to_cache(k)[:, 0], slot, 1)
-        vc = jax.lax.dynamic_update_index_in_dim(
-            kv_cache["v"], to_cache(v)[:, 0], slot, 1)
-        new_cache = {"k": kc, "v": vc}
-        out = decode_attention(q, from_cache(kc), from_cache(vc), length + 1)
+            # Decode: insert the new token. Windowed layers use a rolling cache of
+            # size `window` (slot = len % cache_len); since softmax over the valid
+            # set is order-independent and RoPE is applied pre-cache with absolute
+            # positions, no extra window masking is needed — the cache only ever
+            # holds the last `window` tokens.
+            cache_len = kv_cache["k"].shape[1]
+            length = kv_len
+            if layer_window is not None and layer_window <= cache_len:
+                slot = length % cache_len
+            else:
+                slot = jnp.minimum(length, cache_len - 1)
+            kc = jax.lax.dynamic_update_index_in_dim(
+                kv_cache["k"], to_cache(k)[:, 0], slot, 1)
+            vc = jax.lax.dynamic_update_index_in_dim(
+                kv_cache["v"], to_cache(v)[:, 0], slot, 1)
+            new_cache = {"k": kc, "v": vc}
+            out = decode_attention(q, from_cache(kc), from_cache(vc), length + 1)
 
-    out = out.reshape(b, s, cfg.q_dim)
+        out = out.reshape(b, s, cfg.q_dim)
     out = _maybe_reuse_matmul(f"{site_prefix}_out", out, p["wo"], None, reuse_ctx)
     return out.astype(x.dtype), new_cache
 

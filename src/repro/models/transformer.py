@@ -205,8 +205,10 @@ def _block_forward(
         def mamba_body(carry, xs):
             xx = carry
             mp, ms = xs
-            h, new_ms = ssm_mod.mamba2_forward(mp, cfg, xx, ms, reuse_ctx=None)
-            return xx + h, new_ms
+            with jax.named_scope("layer"):
+                h, new_ms = ssm_mod.mamba2_forward(mp, cfg, xx, ms,
+                                                   reuse_ctx=None)
+                return xx + h, new_ms
 
         if st is None:
             st = jax.tree.map(
@@ -215,7 +217,8 @@ def _block_forward(
                 ).copy(),
                 ssm_mod.init_mamba2_state(cfg, x.shape[0]),
             )
-        x, new_ms = jax.lax.scan(mamba_body, x, (bp["mamba"], st))
+        with jax.named_scope("layer_scan"):
+            x, new_ms = jax.lax.scan(mamba_body, x, (bp["mamba"], st))
         new_state["mamba"] = new_ms
         if shared_block is not None:
             kv = bstate.get("shared_kv") if (bstate and decode) else None
@@ -240,17 +243,20 @@ def _block_forward(
         def local_body(carry, xs):
             xx = carry
             lp, lkv = xs
-            h, new_kv = attention_forward(
-                lp["attn"], cfg, xx, layer_window=cfg.window,
-                positions=positions, kv_cache=lkv, kv_len=kv_len,
-                reuse_ctx=None, site_prefix="attn_local",
-            )
-            xx = xx + h
-            xx = xx + mlp_forward(lp["mlp"], cfg, xx, reuse_ctx=None)
-            return xx, new_kv
+            with jax.named_scope("layer"):
+                h, new_kv = attention_forward(
+                    lp["attn"], cfg, xx, layer_window=cfg.window,
+                    positions=positions, kv_cache=lkv, kv_len=kv_len,
+                    reuse_ctx=None, site_prefix="attn_local",
+                )
+                xx = xx + h
+                xx = xx + mlp_forward(lp["mlp"], cfg, xx, reuse_ctx=None)
+                return xx, new_kv
 
         if decode:
-            x, new_lkv = jax.lax.scan(local_body, x, (bp["local"], bstate["local"]))
+            with jax.named_scope("layer_scan"):
+                x, new_lkv = jax.lax.scan(
+                    local_body, x, (bp["local"], bstate["local"]))
             new_state["local"] = new_lkv
         else:
             x, _ = _unstacked_local(cfg, bp, x, positions, reuse_ctx)
@@ -290,16 +296,18 @@ def _unstacked_local(cfg, bp, x, positions, reuse_ctx):
 
     def body(carry, lp):
         xx = carry
-        h, _ = attention_forward(
-            lp["attn"], cfg, xx, layer_window=cfg.window,
-            positions=positions, kv_cache=None, reuse_ctx=reuse_ctx,
-            site_prefix="attn_local",
-        )
-        xx = xx + h
-        xx = xx + mlp_forward(lp["mlp"], cfg, xx, reuse_ctx=reuse_ctx)
-        return xx, None
+        with jax.named_scope("layer"):
+            h, _ = attention_forward(
+                lp["attn"], cfg, xx, layer_window=cfg.window,
+                positions=positions, kv_cache=None, reuse_ctx=reuse_ctx,
+                site_prefix="attn_local",
+            )
+            xx = xx + h
+            xx = xx + mlp_forward(lp["mlp"], cfg, xx, reuse_ctx=reuse_ctx)
+            return xx, None
 
-    x, _ = jax.lax.scan(body, x, bp["local"])
+    with jax.named_scope("layer_scan"):
+        x, _ = jax.lax.scan(body, x, bp["local"])
     return x, None
 
 
@@ -321,12 +329,13 @@ def embed_inputs(params: Params, cfg: ModelConfig, inputs: dict) -> jax.Array:
 
 
 def output_logits(params: Params, cfg: ModelConfig, h: jax.Array) -> jax.Array:
-    h = apply_norm(params["final_norm"], h, cfg.norm_eps)
-    if "lm_head" in params:
-        return jnp.einsum("bsd,dv->bsv", h, params["lm_head"],
+    with jax.named_scope("head"):
+        h = apply_norm(params["final_norm"], h, cfg.norm_eps)
+        if "lm_head" in params:
+            return jnp.einsum("bsd,dv->bsv", h, params["lm_head"],
+                              preferred_element_type=jnp.float32)
+        return jnp.einsum("bsd,vd->bsv", h, params["embed"],
                           preferred_element_type=jnp.float32)
-    return jnp.einsum("bsd,vd->bsv", h, params["embed"],
-                      preferred_element_type=jnp.float32)
 
 
 # -------------------------------------------------------------------- forward
@@ -343,17 +352,17 @@ def forward(
 ):
     """Returns (hidden [B,S,d], new_decode_state, new_reuse_cache, stats)."""
     decode = decode_state is not None
-    x = embed_inputs(params, cfg, inputs)
-    b, s, _ = x.shape
-
-    if decode:
-        pos0 = decode_state["len"]
-        positions = (pos0 + jnp.arange(s))[None, :].astype(jnp.int32)
-        positions = jnp.broadcast_to(positions, (b, s))
-    else:
-        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-    if cfg.rope == "mrope":
-        positions = jnp.broadcast_to(positions[None], (3, b, s))
+    with jax.named_scope("embed"):
+        x = embed_inputs(params, cfg, inputs)
+        b, s, _ = x.shape
+        if decode:
+            pos0 = decode_state["len"]
+            positions = (pos0 + jnp.arange(s))[None, :].astype(jnp.int32)
+            positions = jnp.broadcast_to(positions, (b, s))
+        else:
+            positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        if cfg.rope == "mrope":
+            positions = jnp.broadcast_to(positions[None], (3, b, s))
 
     shared_block = params.get("shared_block")
     bstates = decode_state["blocks"] if decode else None
@@ -369,12 +378,13 @@ def forward(
         rctx = None
         if reuse_engine is not None and rcache is not None:
             rctx = (reuse_engine, rcache, {})
-        xx, new_bst = _block_forward(
-            cfg, bp, xx, bst,
-            positions=positions, shared_block=shared_block,
-            kv_len=decode_state["len"] if decode else None,
-            reuse_ctx=rctx, decode=decode,
-        )
+        with jax.named_scope("layer"):
+            xx, new_bst = _block_forward(
+                cfg, bp, xx, bst,
+                positions=positions, shared_block=shared_block,
+                kv_len=decode_state["len"] if decode else None,
+                reuse_ctx=rctx, decode=decode,
+            )
         new_rcache = rctx[1] if rctx is not None else rcache
         return xx, (new_bst, new_rcache)
 
@@ -384,9 +394,11 @@ def forward(
         body = jax.checkpoint(body, prevent_cse=False, policy=policy)
 
     xs = (params["blocks"], bstates, reuse_cache)
-    x, (new_bstates, new_rcache) = jax.lax.scan(body, x, xs)
-
-    new_state = None
-    if decode:
-        new_state = {"len": decode_state["len"] + s, "blocks": new_bstates}
+    # Ops under "layer_scan" but outside "layer" are the scan's own per-layer
+    # slicing and write-back of its operands (weights, KV state, reuse cache).
+    with jax.named_scope("layer_scan"):
+        x, (new_bstates, new_rcache) = jax.lax.scan(body, x, xs)
+        new_state = None
+        if decode:
+            new_state = {"len": decode_state["len"] + s, "blocks": new_bstates}
     return x, new_state, new_rcache, stats

@@ -104,7 +104,9 @@ def prefill_step(
         else {"tokens": tokens_or_inputs}
     )
     h, new_state, _, _ = forward(params, cfg, inputs, decode_state=state)
-    logits = output_logits(params, cfg, h[:, -1:])
+    with jax.named_scope("head"):
+        last = h[:, -1:]
+    logits = output_logits(params, cfg, last)
     return logits, new_state
 
 

@@ -80,14 +80,17 @@ def test_check_kernels_refuses_a_host_without_compiled_pallas():
 
 def test_compile_cache_dir(monkeypatch, tmp_path):
     """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
-    launchers cache in `.jax_cache/` at the checkout root."""
+    launchers cache in `.jax_cache/` at the checkout root. Either way an
+    entry's key holds the program's metadata, its named scopes."""
     from repro.launch import compile_cache
 
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert compile_cache.enable_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         default = compile_cache.DEFAULT_DIR
         assert compile_cache.enable_compile_cache() == str(default)
@@ -96,6 +99,8 @@ def test_compile_cache_dir(monkeypatch, tmp_path):
         assert (default.parent / "pyproject.toml").is_file()
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          keyed)
 
 
 def test_interpret_arg_threading():
