@@ -45,7 +45,7 @@ from repro.core.policy import (
     mode_name,
 )
 from repro.core.reuse_cache import ReuseSiteSpec, init_site_cache
-from repro.core.reuse_linear import ReuseStats, reuse_linear
+from repro.core.reuse_linear import ReuseStats, layer_weight, reuse_linear
 from repro.sensor.counters import ShardCtx
 
 
@@ -306,7 +306,10 @@ class ReuseEngine:
         w: jax.Array,
         b: jax.Array | None,
         cache_entry: dict[str, jax.Array],
+        layer: jax.Array | None = None,
     ) -> tuple[jax.Array, dict[str, jax.Array], ReuseStats]:
+        """One site call. `w` is `[K, N]`, or the layer stack `[L, K, N]`
+        with the scan's `layer` index (see reuse_linear)."""
         spec = self.sites[name]
         # Explicitly pinned sites keep the static single-branch dispatch;
         # "auto" sites branch on the ctrl lane the caller's scan sliced.
@@ -315,9 +318,11 @@ class ReuseEngine:
         # window (serve --profile-dir) attributes device time per reuse site.
         with jax.named_scope(f"reuse_site:{name}"):
             if self.shards.get(name):
-                return self._apply_sharded(name, x, w, b, cache_entry, mode)
+                return self._apply_sharded(
+                    name, x, layer_weight(w, layer), b, cache_entry, mode)
             return reuse_linear(
-                x, w, b, cache_entry, spec, mode=mode, impl=self.impl
+                x, w, b, cache_entry, spec, mode=mode, impl=self.impl,
+                layer=layer,
             )
 
     def _apply_sharded(

@@ -29,6 +29,12 @@ explicitly pinned sites, tests and benchmarks.
                          compiled-XLA tier (kernels/xla_tier.py) on hosts with
                          no Pallas lowering — never silent interpret fallback
 
+A site inside a scan over layers may take its weight as the whole layer
+stack `[L, K, N]` with the scan's `layer` index: the reuse branch on the
+"kernel" path hands both to `ops.reuse_matmul`, whose Pallas kernel reads a
+tile-aligned stack's tiles in place; every other consumer slices `w[layer]`
+first and runs as with a `[K, N]` weight.
+
 `spec.exec_path` selects the reuse-mode GEMM within a substrate (see
 kernels/ops.py): "kernel" masked full grid, "ragged" compacted grid,
 "compact" jnp gather, "dense" jnp masked GEMM. "auto" preserves the historic
@@ -49,6 +55,7 @@ from repro.core.delta import DeltaEncoding, delta_encode
 from repro.core.reuse_cache import ReuseSiteSpec, resolve_exec_path
 from repro.core.similarity import ema_update, row_code_similarity
 from repro.kernels import ops
+from repro.kernels.ops import layer_weight
 from repro.quant import dequantize_int8, quantize_int8
 from repro.sensor.counters import (
     ShardCtx,
@@ -98,10 +105,11 @@ def _encode(
 def _basic_eval(
     xm: jax.Array, w: jax.Array, cache: dict[str, jax.Array],
     spec: ReuseSiteSpec, ema_decay: float,
-    shard: ShardCtx | None = None,
+    shard: ShardCtx | None = None, layer: jax.Array | None = None,
 ):
     """ReuseSensor+ReuseOFF: the generated basic kernel (Fig. 7-A) — plain
     quantized GEMM, no delta/cache bookkeeping beyond refreshing state."""
+    w = layer_weight(w, layer)
     m, k = xm.shape
     n = w.shape[-1]
     cur_q = quantize_int8(xm, cache["scale"])
@@ -135,7 +143,7 @@ def _basic_eval(
 def _reuse_eval(
     xm: jax.Array, w: jax.Array, cache: dict[str, jax.Array],
     spec: ReuseSiteSpec, impl: str, ema_decay: float,
-    shard: ShardCtx | None = None,
+    shard: ShardCtx | None = None, layer: jax.Array | None = None,
 ):
     """ReuseSensor+ReuseON: delta-encode against the previous evaluation and
     run the ΔW GEMM on the spec's execution substrate.
@@ -149,6 +157,9 @@ def _reuse_eval(
     n = w.shape[-1]
     enc = _encode(xm, cache, spec, w.dtype, impl)
     path = resolve_exec_path(spec, impl)
+    if path != "kernel":
+        # only the masked full-grid kernel reads a layer stack in place
+        w = layer_weight(w, layer)
     gm, gk = enc.block_mask.shape
     gn = -(-n // spec.block_n)
     gn_own = None if shard is None else owned_panel_count(shard)
@@ -213,7 +224,7 @@ def _reuse_eval(
             block_m=spec.block_m, block_n=spec.block_n,
             block_k=spec.block_k,
             dataflow=spec.dataflow,
-            interpret=interpret, sel=sel,
+            interpret=interpret, sel=sel, layer=layer,
         )
     else:
         raise ValueError(
@@ -267,7 +278,7 @@ def _reuse_eval(
 
 def reuse_linear(
     x: jax.Array,                       # [..., K]
-    w: jax.Array,                       # [K, N]
+    w: jax.Array,                       # [K, N], or [L, K, N] with `layer`
     b: jax.Array | None,
     cache: dict[str, jax.Array],
     spec: ReuseSiteSpec,
@@ -276,6 +287,7 @@ def reuse_linear(
     impl: str = "jnp",
     ema_decay: float = 0.9,
     shard: ShardCtx | None = None,      # model-axis shard accounting context
+    layer: jax.Array | None = None,     # int32 scalar index into a w stack
 ) -> tuple[jax.Array, dict[str, jax.Array], ReuseStats]:
     lead = x.shape[:-1]
     k = x.shape[-1]
@@ -286,10 +298,10 @@ def reuse_linear(
 
     if mode == "basic":
         out, new_cache, stats = _basic_eval(xm, w, cache, spec, ema_decay,
-                                            shard)
+                                            shard, layer)
     elif mode == "reuse":
         out, new_cache, stats = _reuse_eval(xm, w, cache, spec, impl,
-                                            ema_decay, shard)
+                                            ema_decay, shard, layer)
     elif mode is None:
         # Array-resident kernelMode: branch on this layer's ctrl lane. Both
         # branches trace once (identical cache/stats structure); at runtime
@@ -303,8 +315,9 @@ def reuse_linear(
             )
         out, new_cache, stats = jax.lax.cond(
             ctrl["mode_id"] > 0,
-            lambda: _reuse_eval(xm, w, cache, spec, impl, ema_decay, shard),
-            lambda: _basic_eval(xm, w, cache, spec, ema_decay, shard),
+            lambda: _reuse_eval(xm, w, cache, spec, impl, ema_decay, shard,
+                                layer),
+            lambda: _basic_eval(xm, w, cache, spec, ema_decay, shard, layer),
         )
     else:
         raise ValueError(f"unknown mode {mode!r}")

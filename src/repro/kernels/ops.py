@@ -76,11 +76,21 @@ def clamp_budget(max_active_k: int | None, gk: int) -> int:
     return max(1, min(int(max_active_k), gk))
 
 
+def layer_weight(w: jax.Array, layer: jax.Array | None) -> jax.Array:
+    """One layer's `[K, N]` weight: `w` itself, or the `layer`-th slice of a
+    `[L, K, N]` stack."""
+    if layer is None:
+        return w
+    return jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+
+
 def _pad_to(x: jax.Array, mult0: int, mult1: int) -> jax.Array:
-    p0 = (-x.shape[0]) % mult0
-    p1 = (-x.shape[1]) % mult1
+    """Pad the last two dims to tile multiples; an aligned `x` (a layer stack
+    included) is returned as it is, never copied."""
+    p0 = (-x.shape[-2]) % mult0
+    p1 = (-x.shape[-1]) % mult1
     if p0 or p1:
-        x = jnp.pad(x, ((0, p0), (0, p1)))
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, p0), (0, p1)))
     return x
 
 
@@ -96,9 +106,18 @@ def reuse_matmul(
     dataflow: str = "output",
     interpret: bool | None = None,
     sel: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ) -> jax.Array:
-    """Padded/validated entry to the block-skip GEMM (masked full grid)."""
+    """Padded/validated entry to the block-skip GEMM (masked full grid).
+
+    `w` is `[K, N]`, or a layer stack `[L, K, N]` read at the int32 scalar
+    `layer`: the Pallas kernel addresses a tile-aligned stack's tiles in
+    place. The XLA tier, and a stack that would need padding, take the
+    layer's slice, so the padding copies one layer and not the stack."""
     sub = _backend.resolve(interpret)
+    if not (sub.use_pallas and w.shape[-2] % block_k == 0
+            and w.shape[-1] % block_n == 0):
+        w, layer = layer_weight(w, layer), None
     m, n = prev_out.shape
     dp = _pad_to(delta, block_m, block_k)
     wp = _pad_to(w, block_k, block_n)
@@ -109,7 +128,7 @@ def reuse_matmul(
         out = _reuse_matmul_kernel(
             dp, wp, pp, block_mask,
             block_m=block_m, block_n=block_n, block_k=block_k,
-            dataflow=dataflow, interpret=sub.interpret, sel=sel,
+            dataflow=dataflow, interpret=sub.interpret, sel=sel, layer=layer,
         )
     else:
         out = _xla.reuse_matmul_xla(

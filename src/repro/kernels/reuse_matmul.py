@@ -25,6 +25,11 @@ Grid/dataflow:
   exactly the regression the paper reports for 3DUnet — measured in
   benchmarks/dataflow.py.
 
+The weight may be one layer's `[K, N]` or a layer stack `[L, K, N]` with a
+`layer` index. The index is one more scalar-prefetch operand and the weight
+`BlockSpec` addresses `(layer, k, n)`, so a scan over layers hands the kernel
+the stacked parameter itself and no per-layer copy of the weight is made.
+
 Tile sizes default to MXU-aligned (block_k, block_n multiples of 128; block_m
 multiples of 8). Correctness is validated in interpret mode against
 `ref.reuse_matmul_ref` over shape/dtype/mask sweeps.
@@ -94,7 +99,8 @@ def weight_dma_tiles(
 
 
 def _kernel_output_stationary(
-    mask_ref, sel_ref, delta_ref, w_ref, prev_ref, out_ref, acc_ref, *, n_k: int
+    mask_ref, sel_ref, layer_ref, delta_ref, w_ref, prev_ref, out_ref, acc_ref,
+    *, n_k: int,
 ):
     m = pl.program_id(0)
     k = pl.program_id(2)
@@ -115,7 +121,7 @@ def _kernel_output_stationary(
 
 
 def _kernel_input_stationary(
-    mask_ref, sel_ref, delta_ref, w_ref, prev_ref, out_ref, acc_ref,
+    mask_ref, sel_ref, layer_ref, delta_ref, w_ref, prev_ref, out_ref, acc_ref,
     *, n_k: int, block_n: int,
 ):
     """Delta tile resident; the full output row-panel lives in VMEM scratch.
@@ -150,7 +156,7 @@ def _kernel_input_stationary(
 )
 def reuse_matmul(
     delta: jax.Array,       # [M, K] bf16/f32 — zero wherever codes matched
-    w: jax.Array,           # [K, N]
+    w: jax.Array,           # [K, N], or a layer stack [L, K, N]
     prev_out: jax.Array,    # [M, N] f32
     block_mask: jax.Array,  # [gm, gk] int32 (gm = M/block_m, gk = K/block_k)
     *,
@@ -160,10 +166,16 @@ def reuse_matmul(
     dataflow: str = "output",
     interpret: bool = False,
     sel: jax.Array | None = None,  # precomputed _skip_sel(block_mask)
+    layer: jax.Array | None = None,  # int32 scalar; required with a stack
 ) -> jax.Array:
-    """O_c = O_p + Δ·W, skipping weight-tile DMAs and MXU ops for zero tiles."""
+    """O_c = O_p + Δ·W[layer], skipping weight-tile DMAs and MXU ops for zero
+    tiles. A `[K, N]` weight is the one-layer stack."""
+    if w.ndim == 2:
+        assert layer is None, "a layer index needs a [L, K, N] stack"
+        w, layer = w[None], 0
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     m, k = delta.shape
-    k2, n = w.shape
+    k2, n = w.shape[1:]
     assert k == k2, (delta.shape, w.shape)
     assert m % block_m == 0 and k % block_k == 0 and n % block_n == 0, (
         "caller (ops.reuse_linear_kernel) pads to tile multiples",
@@ -179,24 +191,24 @@ def reuse_matmul(
     if dataflow == "output":
         grid = (gm, gn, gk)
 
-        def delta_map(mi, ni, ki, mask, sel):
+        def delta_map(mi, ni, ki, mask, sel, layer):
             return (mi, sel[mi, ki])
 
-        def w_map(mi, ni, ki, mask, sel):
-            return (sel[mi, ki], ni)
+        def w_map(mi, ni, ki, mask, sel, layer):
+            return (layer[0], sel[mi, ki], ni)
 
-        def prev_map(mi, ni, ki, mask, sel):
+        def prev_map(mi, ni, ki, mask, sel, layer):
             return (mi, ni)
 
-        def out_map(mi, ni, ki, mask, sel):
+        def out_map(mi, ni, ki, mask, sel, layer):
             return (mi, ni)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_k), delta_map),
-                pl.BlockSpec((block_k, block_n), w_map),
+                pl.BlockSpec((None, block_k, block_n), w_map),
                 pl.BlockSpec((block_m, block_n), prev_map),
             ],
             out_specs=pl.BlockSpec((block_m, block_n), out_map),
@@ -212,33 +224,34 @@ def reuse_matmul(
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
-        )(block_mask, sel, delta, w, prev_out)
+        )(block_mask, sel, layer, delta, w, prev_out)
 
     elif dataflow == "input":
         grid = (gm, gk, gn)
 
-        def delta_map(mi, ki, ni, mask, sel):
+        def delta_map(mi, ki, ni, mask, sel, layer):
             return (mi, sel[mi, ki])
 
-        def w_map(mi, ki, ni, mask, sel):
+        def w_map(mi, ki, ni, mask, sel, layer):
             # Freeze BOTH coordinates across a fully-masked k sweep so no
             # weight DMA is issued for skipped tiles (n pinned to the last
             # block fetched before entering the masked region).
-            return (sel[mi, ki], jnp.where(mask[mi, ki] != 0, ni, gn - 1))
+            return (layer[0], sel[mi, ki],
+                    jnp.where(mask[mi, ki] != 0, ni, gn - 1))
 
-        def prev_map(mi, ki, ni, mask, sel):
+        def prev_map(mi, ki, ni, mask, sel, layer):
             # prev_out is only consumed during the k == 0 sweep; freeze after.
             return (mi, jnp.where(ki == 0, ni, gn - 1))
 
-        def out_map(mi, ki, ni, mask, sel):
+        def out_map(mi, ki, ni, mask, sel, layer):
             return (mi, ni)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_k), delta_map),
-                pl.BlockSpec((block_k, block_n), w_map),
+                pl.BlockSpec((None, block_k, block_n), w_map),
                 pl.BlockSpec((block_m, block_n), prev_map),
             ],
             out_specs=pl.BlockSpec((block_m, block_n), out_map),
@@ -256,6 +269,6 @@ def reuse_matmul(
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             ),
-        )(block_mask, sel, delta, w, prev_out)
+        )(block_mask, sel, layer, delta, w, prev_out)
 
     raise ValueError(f"unknown dataflow {dataflow!r}")

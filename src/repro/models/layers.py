@@ -14,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -314,12 +314,37 @@ def decode_attention(
     return out.astype(q.dtype)
 
 
+class LayerWeight(NamedTuple):
+    """A reuse site's weight as the whole layer stack `[L, K, N]` with the
+    scan's layer index: the site reads a tile-aligned layer's tiles from the
+    stack, so the decode scan makes no per-layer copy of the weight."""
+
+    stack: jax.Array
+    layer: jax.Array
+
+
+# Where each reuse site of a plain block reads its weight in the block's
+# parameters; the decode scan hands these to their sites as LayerWeights.
+# Sites of other blocks (moe_shared_*, attn_global_*, mlp_global_*,
+# shared_attn_*, shared_mlp_*, the recurrent mixers') get the weight the
+# scan sliced.
+SITE_WEIGHTS = {
+    "attn_qkv": ("attn", "wqkv"),
+    "attn_out": ("attn", "wo"),
+    "mlp_in": ("mlp", "wi"),
+    "mlp_out": ("mlp", "wo"),
+}
+
+
 def _maybe_reuse_matmul(name, x, w, b, reuse_ctx):
-    """Route a linear site through the ReuseEngine when serving with reuse."""
+    """Route a linear site through the ReuseEngine when serving with reuse.
+    A LayerWeight comes only with a site the cache holds."""
     if reuse_ctx is not None:
         engine, cache, stats = reuse_ctx
         if name in cache:
-            out, new_entry, st = engine.apply(name, x, w, b, cache[name])
+            w, layer = w if isinstance(w, LayerWeight) else (w, None)
+            out, new_entry, st = engine.apply(name, x, w, b, cache[name],
+                                              layer=layer)
             cache[name] = new_entry
             stats[name] = st
             return out

@@ -22,6 +22,11 @@ superblock body its layer's prev_q/prev_out hands it that layer's control
 lane too. The layer body branches on the sliced mode id with lax.cond inside
 reuse_linear — a deep stack runs mixed reuse/basic modes in ONE trace, and a
 host-side per-layer mode flip between steps never retraces the scan.
+
+The reuse sites' weights are the exception in decode through a reuse engine:
+the scan leaves them whole and hands each site its stack with the scan's
+layer index (a LayerWeight), so the GEMM kernel reads a tile-aligned
+layer's tiles in place instead of from a per-layer copy.
 """
 
 from __future__ import annotations
@@ -31,11 +36,14 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (
+    SITE_WEIGHTS,
+    LayerWeight,
     Params,
     _dense_init,
     apply_norm,
@@ -311,6 +319,29 @@ def _unstacked_local(cfg, bp, x, positions, reuse_ctx):
     return x, None
 
 
+# --------------------------------------------------- in-place site weights
+
+
+def _lift_site_weights(blocks: Params, reuse_cache: dict):
+    """Split the weight stacks of the reuse sites in `reuse_cache` off the
+    scanned block parameters: (the rest, {param path: [L, K, N] stack})."""
+    paths = [p for site, p in SITE_WEIGHTS.items()
+             if site in reuse_cache and p[1] in blocks.get(p[0], {})]
+    rest = dict(blocks)
+    for group, _ in paths:
+        rest[group] = dict(blocks[group])
+    return rest, {(g, n): rest[g].pop(n) for g, n in paths}
+
+
+def _with_layer_weights(bp: Params, stacks: dict, layer) -> Params:
+    """A layer's block parameters with each lifted stack put back as a
+    LayerWeight at its path."""
+    bp = dict(bp)
+    for (group, name), stack in stacks.items():
+        bp[group] = {**bp[group], name: LayerWeight(stack, layer)}
+    return bp
+
+
 # ------------------------------------------------------------------ embedding
 
 
@@ -366,6 +397,10 @@ def forward(
 
     shared_block = params.get("shared_block")
     bstates = decode_state["blocks"] if decode else None
+    blocks, stacks = params["blocks"], {}
+    if decode and reuse_engine is not None and reuse_cache is not None:
+        blocks, stacks = _lift_site_weights(blocks, reuse_cache)
+    layers = np.arange(cfg.n_superblocks, dtype=np.int32) if stacks else None
 
     stats: dict[str, Any] = {}
 
@@ -374,7 +409,9 @@ def forward(
         # rcache is THIS superblock's slice of every reuse site's cache —
         # including the ctrl lane whose mode id the reuse dispatch branches
         # on, so kernelMode is per-layer inside the scan
-        bp, bst, rcache = xs
+        bp, bst, rcache, layer = xs
+        if stacks:
+            bp = _with_layer_weights(bp, stacks, layer)
         rctx = None
         if reuse_engine is not None and rcache is not None:
             rctx = (reuse_engine, rcache, {})
@@ -393,9 +430,10 @@ def forward(
                   if cfg.remat_policy == "dots" else None)
         body = jax.checkpoint(body, prevent_cse=False, policy=policy)
 
-    xs = (params["blocks"], bstates, reuse_cache)
+    xs = (blocks, bstates, reuse_cache, layers)
     # Ops under "layer_scan" but outside "layer" are the scan's own per-layer
-    # slicing and write-back of its operands (weights, KV state, reuse cache).
+    # slicing and write-back of its operands (KV state, reuse cache, and the
+    # weights that no reuse site reads in place).
     with jax.named_scope("layer_scan"):
         x, (new_bstates, new_rcache) = jax.lax.scan(body, x, xs)
         new_state = None
