@@ -37,14 +37,14 @@ ROOT = HERE.parents[1]
 
 
 def readings(cell, seeds, *, require_chip=True, out=None, fault=None):
-    from chip import check, faults, harness, reference, weights
+    from chip import check, faults, harness, weights
     from chip import system as system_mod
 
     harness.device_info(cell.chips, require_chip)
     if require_chip:
         harness.enable_persistent_cache()
-    model = cell.conf["model"]
-    spec = reference.spec_of(model)
+    model, family = cell.conf["model"], cell.family
+    spec = family.spec_of(model)
     control = check.codes(cell.conf, "control_codes")
     sut = params = None
     rows = []
@@ -53,9 +53,9 @@ def readings(cell, seeds, *, require_chip=True, out=None, fault=None):
         if sut is not None:
             sut.params = None
         gc.collect()
-        params = weights.make_params(model, seed)
+        params = weights.make_params(family.layout(model), seed)
         if sut is None:
-            sut = system_mod.build(cell.conf, cell.traffic, params,
+            sut = system_mod.build(cell.conf, family, cell.traffic, params,
                                    check_kernels=require_chip)
             steps = (sut.decode, sut.sample)
         sut.decode, sut.sample = steps
@@ -79,10 +79,11 @@ def readings(cell, seeds, *, require_chip=True, out=None, fault=None):
         for key in {k for k, _, _ in check.NUMBERS.values()}:
             ref = check.codes(cell.conf, key)
             judged = {"fault" if fault else "program":
-                      check.served(params, spec, picked, ref=ref, ranks=True)}
+                      check.served(family, spec, params, picked, ref=ref,
+                                   ranks=True)}
             if fault is None:
-                judged["control"] = check.control(params, spec, picked,
-                                                  control, ref=ref,
+                judged["control"] = check.control(family, spec, params,
+                                                  picked, control, ref=ref,
                                                   ranks=True)
             names = [n for n, v in check.NUMBERS.items() if v[0] == key]
             for side, j in judged.items():

@@ -1,14 +1,16 @@
 """The comparison that decides `correct`.
 
 A served request is a prompt and the tokens the timed path emitted for it.
-The reference runs once over the prompt with those tokens appended
-(teacher-forced), and each served token is judged by how far its reference
-logit lies below the reference's best logit at that position (its gap: 0
-where the served token is the reference's own greedy choice, and the size of
-the miss otherwise), and by how many tokens the reference ranks above it
-(its rank, 0 for the greedy choice). A run's numbers are the widest and the
-mean of these over a sample of its finished requests, drawn from the seed
-(`NUMBERS`); the cell's limits file names the ones it compares.
+The reference (the configuration's architecture module's `final_hidden`,
+then the shared head of `reference.py`) runs once over the prompt with those
+tokens appended (teacher-forced), and each served token is judged by how far
+its reference logit lies below the reference's best logit at that position
+(its gap: 0 where the served token is the reference's own greedy choice,
+and the size of the miss otherwise), and by how many tokens the reference
+ranks above it (its rank, 0 for the greedy choice). A run's numbers are the
+widest and the mean of these over a sample of its finished requests, drawn
+from the seed (`NUMBERS`); the cell's limits file names the ones it
+compares.
 
 The control puts the reference, computed at a lower precision, in the
 program's place: at each position of the same prompts and tokens it reads the
@@ -96,21 +98,22 @@ def _wants_rank(names, key) -> bool:
                for n in names)
 
 
-def compared(conf: dict, names, params, spec, picked) -> dict:
+def compared(conf: dict, names, family, spec, params, picked) -> dict:
     """{name: number} of the served tokens of `picked`, each reference run
-    once."""
-    judged = {key: served(params, spec, picked, ref=codes(conf, key),
-                          ranks=_wants_rank(names, key))
+    once. `family` is the configuration's architecture module and `spec`
+    what its `spec_of` gives for the configuration."""
+    judged = {key: served(family, spec, params, picked,
+                          ref=codes(conf, key), ranks=_wants_rank(names, key))
               for key in {NUMBERS[n][0] for n in names}}
     return numbers_of(judged, names)
 
 
-def _judge(params, spec, h, best, tokens, ranks: bool) -> dict:
-    got = reference.head_logits_at(params, spec, h, tokens)
+def _judge(params, h, best, tokens, ranks: bool) -> dict:
+    got = reference.head_logits_at(params, h, tokens)
     out = {"gap": np.asarray(best - got, np.float64)}
     if ranks:
         out["rank"] = np.asarray(reference.head_count_above(
-            params, spec, h, tokens, got), np.float64)
+            params, h, tokens, got), np.float64)
     return out
 
 
@@ -120,13 +123,13 @@ def _join(parts: list[dict]) -> dict:
     return {q: np.concatenate([p[q] for p in parts]) for q in parts[0]}
 
 
-def served(params, spec, requests: list[Served], ref=None,
+def served(family, spec, params, requests: list[Served], ref=None,
            ranks: bool = False) -> dict:
     """{"gap": ..., "rank": ...} of each served token of every request
     against the reference (rank only when asked for). `ref` is the
     configuration's stated (bits, clip) of the reuse-site codes in decode
     steps. A token outside the vocabulary reads infinite."""
-    vocab = spec[9]
+    vocab = reference.vocab_size(params)
     parts = []
     for r in requests:
         if np.any((r.tokens < 0) | (r.tokens >= vocab)):
@@ -134,14 +137,14 @@ def served(params, spec, requests: list[Served], ref=None,
             parts.append({"gap": bad, **({"rank": bad} if ranks else {})})
             continue
         p = len(r.prompt)
-        h = reference.final_hidden(params, spec, _sequence(r),
-                                   quant=_at(ref, p))[p - 1:]
-        best, _ = reference.head_max_argmax(params, spec, h)
-        parts.append(_judge(params, spec, h, best, r.tokens, ranks))
+        h = family.final_hidden(params, spec, _sequence(r),
+                                quant=_at(ref, p))[p - 1:]
+        best, _ = reference.head_max_argmax(params, h)
+        parts.append(_judge(params, h, best, r.tokens, ranks))
     return _join(parts)
 
 
-def control(params, spec, requests: list[Served], quant, ref=None,
+def control(family, spec, params, requests: list[Served], quant, ref=None,
             ranks: bool = False) -> dict:
     """The gap (and rank) of the token the reference with `quant` codes at
     the reuse sites puts first, at each position of the same prompts and
@@ -150,11 +153,10 @@ def control(params, spec, requests: list[Served], quant, ref=None,
     for r in requests:
         p = len(r.prompt)
         seq = _sequence(r)
-        h = reference.final_hidden(params, spec, seq,
-                                   quant=_at(ref, p))[p - 1:]
-        best, _ = reference.head_max_argmax(params, spec, h)
-        hq = reference.final_hidden(params, spec, seq,
-                                    quant=_at(quant, p))[p - 1:]
-        _, pick = reference.head_max_argmax(params, spec, hq)
-        parts.append(_judge(params, spec, h, best, pick, ranks))
+        h = family.final_hidden(params, spec, seq, quant=_at(ref, p))[p - 1:]
+        best, _ = reference.head_max_argmax(params, h)
+        hq = family.final_hidden(params, spec, seq,
+                                 quant=_at(quant, p))[p - 1:]
+        _, pick = reference.head_max_argmax(params, hq)
+        parts.append(_judge(params, h, best, pick, ranks))
     return _join(parts)

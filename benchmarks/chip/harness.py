@@ -1,9 +1,11 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
 Everything that belongs to one configuration, traffic mix or per-layer
-metric is data found by name: `configs/<config>.json`, `traffic/<traffic>.json`
-(whose `kind` names `generators/<kind>.py`), `limits/<cell>.json` and
-`metrics/<metric>.py`. This file holds no branch on any of those names.
+metric is data found by name: `configs/<config>.json` (whose published
+`model.model_type` names its architecture module `arch/<model_type>.py`),
+`traffic/<traffic>.json` (whose `kind` names `generators/<kind>.py`),
+`limits/<cell>.json` and `metrics/<metric>.py`. This file holds no branch on
+any of those names.
 """
 
 from __future__ import annotations
@@ -20,6 +22,8 @@ import sys
 import time
 
 import numpy as np
+
+from chip import arch
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -49,6 +53,7 @@ def load_module(path: pathlib.Path):
 class Cell:
     name: str
     conf: dict
+    family: object         # the configuration's architecture module
     traffic: dict
     limits: dict
     chips: int
@@ -62,13 +67,15 @@ def load_cell(bench: dict, workload: str, data_dir: pathlib.Path) -> Cell:
         raise SystemExit(f"unknown workload {workload!r}; known: "
                          f"{sorted(cells)}")
     w = cells[workload]
+    conf = load_json(data_dir / "configs" / f"{w['config']}.json")
 
     def here(m):
         return "workloads" not in m or workload in m["workloads"]
 
     return Cell(
         name=workload,
-        conf=load_json(data_dir / "configs" / f"{w['config']}.json"),
+        conf=conf,
+        family=arch.resolve(conf["model"]),
         traffic=load_json(data_dir / "traffic" / f"{w['traffic']}.json"),
         limits=load_json(data_dir / "limits" / f"{workload}.json"),
         chips=int(w["chips"]),
@@ -147,7 +154,7 @@ class Context:
     system: object
     window: object
     trace: object          # trace_reduce.Trace, or None
-    shape: object          # work.ModelShape
+    shape: object          # the architecture module's shape(model)
     peak: object           # peaks.Peak, or None off the chip
     tiles: dict            # site -> (skipped, computed) over the window
 
@@ -190,7 +197,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     import jax
     import jax.profiler
 
-    from chip import check, reference, weights, work
+    from chip import check, weights
     from chip import system as system_mod
     from chip.peaks import peak_for
 
@@ -199,10 +206,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         enable_persistent_cache()
     compiles = CompileCount()
 
-    model = cell.conf["model"]
-    params = weights.make_params(model, seed)
+    model, family = cell.conf["model"], cell.family
+    params = weights.make_params(family.layout(model), seed)
     jax.block_until_ready(params)
-    sut = system_mod.build(cell.conf, cell.traffic, params,
+    sut = system_mod.build(cell.conf, family, cell.traffic, params,
                            check_kernels=require_chip)
     if fault is not None:
         fault(sut)
@@ -242,11 +249,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     # ------------------------------------------------------------ the check
     sut.free()
     gc.collect()
-    spec = reference.spec_of(model)
+    spec = family.spec_of(model)
     picked = check.sample(window.requests, cell.traffic["check_requests"],
                           seed)
     limits = {n: float(v["limit"]) for n, v in cell.limits.items()}
-    numbers = check.compared(cell.conf, limits, params, spec, picked)
+    numbers = check.compared(cell.conf, limits, family, spec, params, picked)
     numbers["compilations_in_window"] = in_window
     limits["compilations_in_window"] = 0
     n_tok = sum(len(r.tokens) for r in picked)
@@ -267,7 +274,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
         tr = trace_reduce.load(trace_reduce.find(str(trace_dir)), scopes)
         ctx = Context(cell=cell, system=sut, window=window, trace=tr,
-                      shape=work.ModelShape.from_published(model),
+                      shape=family.shape(model),
                       peak=peak_for(info["kind"]) if require_chip else None,
                       tiles=tiles)
         result["metrics"] = {}

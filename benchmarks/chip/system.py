@@ -3,8 +3,10 @@
 Built from a configuration file's `program` group: the program's own config
 entry cut to the stated depth, with the stated field overrides, and the
 reuse engine of `serve --reuse` (Pallas substrate, default policy, no
-controller). Every published key the program has a field for is checked
-against that field, so the program runs what the file states.
+controller). Every published key the program has a field for (the
+architecture module's `program_fields`) is checked against that field, and
+the module's `check_program` against the program's structure, so the program
+runs what the file states.
 """
 
 from __future__ import annotations
@@ -31,39 +33,20 @@ from repro.serve.serve_step import (
     jit_prefill,
 )
 
-_ACT = {"silu": "swiglu", "relu2": "relu2"}
 
-# published key -> (program field, how the file's value reads as the field's)
-_FIELDS = {
-    "num_hidden_layers": ("n_layers", int),
-    "hidden_size": ("d_model", int),
-    "num_attention_heads": ("n_heads", int),
-    "num_key_value_heads": ("n_kv_heads", int),
-    "head_dim": ("head_dim", int),
-    "intermediate_size": ("d_ff", int),
-    "vocab_size": ("vocab", int),
-    "rms_norm_eps": ("norm_eps", float),
-    "rope_theta": ("rope_theta", float),
-    "tie_word_embeddings": ("tie_embeddings", bool),
-    "qk_norm": ("qk_norm", bool),
-    "attention_bias": ("qkv_bias", bool),
-    "hidden_act": ("mlp_kind", _ACT.__getitem__),
-    "torch_dtype": ("param_dtype", str),
-}
-
-
-def program_config(conf: dict):
+def program_config(conf: dict, family):
+    """The program's config of `conf`, checked against the file through its
+    architecture module `family` (`arch.resolve`)."""
     prog = conf["program"]
     cfg = get_config(prog["arch"]).with_layers(prog["layers"])
     cfg = dataclasses.replace(cfg, **prog.get("overrides", {}))
     wrong = {}
-    for key, (field, conv) in _FIELDS.items():
+    for key, (field, conv) in family.program_fields.items():
         if key in conf["model"]:
             want = conv(conf["model"][key])
             if getattr(cfg, field) != want:
                 wrong[key] = (getattr(cfg, field), want)
-    if cfg.attn_kind != "full" or cfg.rope != "rope" or cfg.n_experts:
-        wrong["block"] = (cfg.attn_kind, cfg.rope, cfg.n_experts)
+    wrong.update(family.check_program(cfg))
     if wrong:
         raise ValueError(f"program config {cfg.name} differs from "
                          f"{conf['name']}: {wrong} (program, file)")
@@ -158,8 +141,9 @@ def _tile_sums(rcache):
             for k, e in rcache.items()}
 
 
-def build(conf: dict, traffic: dict, params, *, check_kernels: bool) -> System:
-    cfg = program_config(conf)
+def build(conf: dict, family, traffic: dict, params, *,
+          check_kernels: bool) -> System:
+    cfg = program_config(conf, family)
     want = jax.eval_shape(init_params, cfg, jax.random.PRNGKey(0))
     if _aval(params) != _aval(want):
         raise ValueError("benchmark weights do not match the program's "

@@ -6,7 +6,9 @@ import json
 import pathlib
 import re
 
-from chip import check, harness, reference, system
+import pytest
+
+from chip import arch, check, harness, system
 
 ROOT = pathlib.Path(__file__).resolve().parents[3]
 HERE = ROOT / "benchmarks" / "chip"
@@ -37,6 +39,18 @@ def test_config_files_are_what_the_program_runs():
     for c in BENCH["configs"]:
         conf = json.loads((ROOT / c["file"]).read_text())
         assert conf["name"] == c["name"] and conf["reduced"] == c["reduced"]
-        cfg = system.program_config(conf)
+        family = arch.resolve(conf["model"])
+        assert family.__name__ == f"chip.arch.{conf['model']['model_type']}"
+        cfg = system.program_config(conf, family)
         assert cfg.n_layers == conf["model"]["num_hidden_layers"]
-        reference.spec_of(conf["model"])
+        family.spec_of(conf["model"])
+
+
+def test_unknown_model_type_names_the_file_to_add():
+    conf = json.loads((HERE / "configs" / "qwen3-32b-l8.json").read_text())
+    model = dict(conf["model"], model_type="no_such_family")
+    with pytest.raises(LookupError, match=re.escape(
+            str(HERE / "arch" / "no_such_family.py"))):
+        arch.resolve(model)
+    with pytest.raises(LookupError, match="no model_type"):
+        arch.resolve({k: v for k, v in model.items() if k != "model_type"})

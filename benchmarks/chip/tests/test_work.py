@@ -4,6 +4,7 @@ table's refusal of an unknown chip."""
 import pytest
 
 from chip import work
+from chip.arch import dense
 from chip.peaks import PEAKS, peak_for
 
 V5E = peak_for("TPU v5 lite")
@@ -30,9 +31,9 @@ def test_skipped_tiles_are_not_work():
 
 
 def test_model_flops_by_hand():
-    shape = work.ModelShape(layers=8, d_model=5120, heads=64, kv_heads=8,
-                            head_dim=128, ffn=25600, vocab=151936,
-                            gated=True)
+    shape = dense.DenseShape(layers=8, d_model=5120, heads=64, kv_heads=8,
+                             head_dim=128, ffn=25600, vocab=151936,
+                             gated=True)
     per_layer = 5120 * (8192 + 2048) + 8192 * 5120 + 3 * 5120 * 25600
     assert shape.linear_params() == 8 * per_layer + 5120 * 151936
     # one decode row at position 511 attends over 512 keys in 8 layers

@@ -1,12 +1,10 @@
 """Seeded random weights, made by the benchmark and handed to the program.
 
 The weights come from `--seed` alone, in one jitted call on the device, in
-the type they are served in. They are laid out as the program loads them (a
-checkpoint format, like any loader's): the stacked per-layer matrices of a
-dense decoder, q|k|v fused in one matrix, gate|up fused in one matrix for a
-gated MLP, and each norm weight stored as its offset from 1. The reference
-(`reference.py`) reads the same arrays through that layout; neither the
-weights nor the layout come from the program's own initialiser.
+the type they are served in, laid out as the configuration's architecture
+module says (`arch/<model_type>.py`, `layout`): as the program loads them, a
+checkpoint format like any loader's. The reference reads the same arrays;
+neither the weights nor the layout come from the program's own initialiser.
 """
 
 from __future__ import annotations
@@ -26,36 +24,6 @@ def seed_key(seed: int) -> jax.Array:
     s = int(seed) % 2**64
     words = np.array([s >> 32, s & 0xFFFFFFFF], dtype=np.uint32)
     return jax.random.wrap_key_data(jnp.asarray(words), impl="threefry2x32")
-
-
-def layout(model: dict) -> dict:
-    """{path: (shape, dtype, std)} of every parameter; std None marks a
-    norm weight (stored as an offset from 1)."""
-    d, v, n = model["hidden_size"], model["vocab_size"], \
-        model["num_hidden_layers"]
-    q = model["num_attention_heads"] * model["head_dim"]
-    kv = model["num_key_value_heads"] * model["head_dim"]
-    f = model["intermediate_size"]
-    wide = 2 * f if model["hidden_act"] == "silu" else f
-    wt = jnp.dtype(model["torch_dtype"])
-    f32 = jnp.dtype(jnp.float32)
-    out = {
-        "embed": ((v, d), wt, d ** -0.5),
-        "blocks/attn/wqkv": ((n, d, q + 2 * kv), wt, d ** -0.5),
-        "blocks/attn/wo": ((n, q, d), wt, q ** -0.5),
-        "blocks/attn/norm/scale": ((n, d), f32, None),
-        "blocks/mlp/wi": ((n, d, wide), wt, d ** -0.5),
-        "blocks/mlp/wo": ((n, f, d), wt, f ** -0.5),
-        "blocks/mlp/norm/scale": ((n, d), f32, None),
-        "final_norm/scale": ((d,), f32, None),
-    }
-    if model["qk_norm"]:
-        hd = model["head_dim"]
-        out["blocks/attn/q_norm/scale"] = ((n, hd), f32, None)
-        out["blocks/attn/k_norm/scale"] = ((n, hd), f32, None)
-    if not model["tie_word_embeddings"]:
-        out["lm_head"] = ((d, v), wt, d ** -0.5)
-    return out
 
 
 def _nest(flat: dict) -> dict:
@@ -80,8 +48,9 @@ def _draw(spec: tuple, key: jax.Array) -> dict:
     return _nest(flat)
 
 
-def make_params(model: dict, seed: int) -> dict:
-    """Every weight of `model`, drawn from `seed` on the default device."""
+def make_params(layout: dict, seed: int) -> dict:
+    """Every weight of `layout` ({path: (shape, dtype, std)}, std None for
+    a norm weight), drawn from `seed` on the default device."""
     spec = tuple((p, s, jnp.dtype(dt).name, std)
-                 for p, (s, dt, std) in sorted(layout(model).items()))
+                 for p, (s, dt, std) in sorted(layout.items()))
     return _draw(spec, seed_key(seed))
