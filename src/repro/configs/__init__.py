@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + reduced smoke variants."""
+"""Architecture registry: the assigned configs + reduced smoke variants."""
 
 from __future__ import annotations
 
@@ -6,6 +6,7 @@ from repro.configs.base import ModelConfig
 from repro.configs.llama4_scout_17b_a16e import CONFIG as LLAMA4_SCOUT
 from repro.configs.mixtral_8x7b import CONFIG as MIXTRAL_8X7B
 from repro.configs.nemotron_4_15b import CONFIG as NEMOTRON_4_15B
+from repro.configs.nemotron_h_47b import CONFIG as NEMOTRON_H_47B
 from repro.configs.gemma3_12b import CONFIG as GEMMA3_12B
 from repro.configs.qwen3_32b import CONFIG as QWEN3_32B
 from repro.configs.qwen2_72b import CONFIG as QWEN2_72B
@@ -27,6 +28,7 @@ ARCHS: dict[str, ModelConfig] = {
         HUBERT_XLARGE,
         QWEN2_VL_7B,
         ZAMBA2_2P7B,
+        NEMOTRON_H_47B,
     ]
 }
 

@@ -10,6 +10,9 @@ from __future__ import annotations
 
 import dataclasses
 
+# A pattern letter's layer kind: its parameter, state and reuse-site group.
+LAYER_KINDS = {"M": "mamba", "-": "mlp", "*": "attn"}
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -46,7 +49,12 @@ class ModelConfig:
     ssm_kind: str = "none"       # rwkv6 | mamba2
     ssm_state: int = 64
     ssm_head_dim: int = 64
+    ssm_groups: int = 1          # mamba2: B/C groups; head h reads h // (H/G)
     hybrid_attn_every: int = 0   # zamba2: one shared attn block per N ssm blocks
+    # One letter per layer, in order (`hybrid_override_pattern`): "M" a
+    # Mamba2 mixer, "-" an MLP, "*" attention, each with its own pre-norm
+    # and residual. Empty: the stack is uniform superblocks.
+    layer_pattern: str = ""
 
     # --- misc ---
     tie_embeddings: bool = True
@@ -77,6 +85,14 @@ class ModelConfig:
     # (§Perf iteration 3 for collective-bound training).
     remat_policy: str = "full"
     scan_layers: bool = True     # scan over stacked superblocks
+
+    def __post_init__(self):
+        if self.layer_pattern and (
+                len(self.layer_pattern) != self.n_layers
+                or set(self.layer_pattern) - set(LAYER_KINDS)):
+            raise ValueError(
+                f"{self.name}: layer_pattern {self.layer_pattern!r} must be "
+                f"{self.n_layers} letters of {''.join(LAYER_KINDS)!r}")
 
     # ---- derived ----
     @property
@@ -112,6 +128,10 @@ class ModelConfig:
             self.n_layers, self.superblock_layers)
         return self.n_layers // self.superblock_layers
 
+    def kind_layers(self, letter: str) -> int:
+        """How many layers of a pattern stack are of the kind `letter`."""
+        return self.layer_pattern.count(letter)
+
     @property
     def q_dim(self) -> int:
         return self.n_heads * self.head_dim
@@ -136,12 +156,17 @@ class ModelConfig:
             per_mlp = 3 * d * f
         else:
             per_mlp = 2 * d * f
-        if self.ssm_kind == "rwkv6":
+        di = self.d_inner
+        per_ssm = (d * (2 * di + 2 * self.ssm_groups * self.ssm_state
+                        + self.n_ssm_heads) + di * d)
+        if self.layer_pattern:
+            n += (self.kind_layers("M") * per_ssm
+                  + self.kind_layers("-") * per_mlp
+                  + self.kind_layers("*") * per_attn)
+        elif self.ssm_kind == "rwkv6":
             per_layer = 5 * d * d + d * d + per_mlp  # r,k,v,g,w(+lora approx) + out
             n += self.n_layers * per_layer
         elif self.ssm_kind == "mamba2":
-            di = self.d_inner
-            per_ssm = d * (2 * di + 2 * self.ssm_state + self.n_ssm_heads) + di * d
             n_ssm_layers = self.n_layers
             n += n_ssm_layers * per_ssm
             if self.hybrid_attn_every:
@@ -175,14 +200,20 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: n_layers={n_layers} must be a positive "
                 f"multiple of its {self.superblock_layers}-layer superblock")
-        return dataclasses.replace(self, n_layers=n_layers)
+        return dataclasses.replace(
+            self, n_layers=n_layers,
+            layer_pattern=self.layer_pattern[:n_layers])
 
     def reduced(self) -> "ModelConfig":
         """Same family, smoke-test scale. Keeps every structural feature."""
+        # a pattern stack keeps every kind, two Mamba2 layers among them
+        pattern = "M-M*-" if self.layer_pattern else ""
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
-            n_layers=max(2 * self.superblock_layers, self.superblock_layers),
+            n_layers=len(pattern) or 2 * self.superblock_layers,
+            layer_pattern=pattern,
+            ssm_groups=min(self.ssm_groups, 2),
             d_model=128,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads < self.n_heads else 4,

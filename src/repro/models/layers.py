@@ -323,16 +323,18 @@ class LayerWeight(NamedTuple):
     layer: jax.Array
 
 
-# Where each reuse site of a plain block reads its weight in the block's
-# parameters; the decode scan hands these to their sites as LayerWeights.
-# Sites of other blocks (moe_shared_*, attn_global_*, mlp_global_*,
-# shared_attn_*, shared_mlp_*, the recurrent mixers') get the weight the
-# scan sliced.
+# Where each reuse site of a plain block or a pattern stack reads its weight
+# in the stacked parameters (group, name); decode hands these to their sites
+# as LayerWeights. Sites of other blocks (moe_shared_*, attn_global_*,
+# mlp_global_*, shared_attn_*, shared_mlp_*, the RWKV mixers') get the
+# weight the scan sliced.
 SITE_WEIGHTS = {
     "attn_qkv": ("attn", "wqkv"),
     "attn_out": ("attn", "wo"),
     "mlp_in": ("mlp", "wi"),
     "mlp_out": ("mlp", "wo"),
+    "mamba_in": ("mamba", "in_proj"),
+    "mamba_out": ("mamba", "out_proj"),
 }
 
 

@@ -14,9 +14,10 @@ data-dependent per-channel decay w_t, bonus u, per-head state S ∈ R^{dk×dv}:
     out_t = r_t · (diag(u)·k_tᵀ v_t + S_t);   S_{t+1} = diag(w_t)·S_t + k_tᵀ v_t
 
 Mamba2 (arXiv:2405.21060): scalar-per-head decay a_t = exp(dt_t·A), state
-h ∈ R^{heads×headdim×state}:
+h ∈ R^{heads×headdim×state}, B and C shared by the heads of a group:
 
     h_t = a_t·h_{t-1} + dt_t · x_t ⊗ B_t;     y_t = h_t · C_t + D·x_t
+    out = W_out · groupnorm(y ⊙ silu(z))      (gate first, then the norm)
 """
 
 from __future__ import annotations
@@ -206,13 +207,13 @@ MAMBA_CONV_K = 4
 def init_mamba2(cfg: ModelConfig, key) -> Params:
     d = cfg.d_model
     di = cfg.d_inner
-    st = cfg.ssm_state
+    bc = 2 * cfg.ssm_groups * cfg.ssm_state
     nh = cfg.n_ssm_heads
     ks = jax.random.split(key, 4)
-    conv_ch = di + 2 * st
+    conv_ch = di + bc
     return {
         "norm": init_norm(d),
-        "in_proj": _dense_init(ks[0], (d, 2 * di + 2 * st + nh), dtype=cfg.dtype),
+        "in_proj": _dense_init(ks[0], (d, 2 * di + bc + nh), dtype=cfg.dtype),
         "conv_w": (jax.random.normal(ks[1], (MAMBA_CONV_K, conv_ch), jnp.float32)
                    * 0.1).astype(cfg.dtype),
         "conv_b": jnp.zeros((conv_ch,), jnp.float32),
@@ -225,75 +226,110 @@ def init_mamba2(cfg: ModelConfig, key) -> Params:
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array, conv_state: jax.Array):
-    """Depthwise causal conv, kernel K. x: [B, S, C]; conv_state: [B, K-1, C]."""
+    """Depthwise causal conv, kernel K, in float32. x: [B, S, C];
+    conv_state: [B, K-1, C], the last K-1 inputs, kept in x's dtype."""
     k = w.shape[0]
     xp = jnp.concatenate([conv_state.astype(x.dtype), x], axis=1)
+    xf = xp.astype(jnp.float32)
     out = sum(
-        xp[:, i : i + x.shape[1]] * w[i].astype(x.dtype) for i in range(k)
+        xf[:, i : i + x.shape[1]] * w[i].astype(jnp.float32) for i in range(k)
     )
-    out = out + b.astype(x.dtype)
+    out = out + b.astype(jnp.float32)
     new_state = xp[:, -(k - 1):] if k > 1 else conv_state
     return out, new_state
 
 
+def _gated_group_norm(y, z, scale, groups: int, eps: float):
+    """Mamba2's gated RMSNorm as published (`norm_before_gate=False`): gate
+    y by silu(z) first, then normalise each of `groups` equal channel groups
+    on its own. Float32 in and out."""
+    g = y * jax.nn.silu(z)
+    gg = g.reshape(*g.shape[:-1], groups, -1)
+    gg = gg * jax.lax.rsqrt(jnp.mean(gg * gg, axis=-1, keepdims=True) + eps)
+    return gg.reshape(g.shape) * (1.0 + scale.astype(jnp.float32))
+
+
 def mamba2_forward(
     p: Params, cfg: ModelConfig, x: jax.Array, state: dict, *,
-    reuse_ctx=None, prefix: str = "mamba",
+    reuse_ctx=None, prefix: str = "mamba", layer: int | None = None,
+    chunk: int = 256,
 ) -> tuple[jax.Array, dict]:
-    """x: [B, S, d]; state: {"conv": [B, K-1, C], "h": [B, nh, hd, state]}."""
+    """x: [B, S, d]; state: {"conv": [B, K-1, C], "h": [B, nh, hd, state]}.
+    With `layer`, each state leaf is a stack of layers [L, ...]: the mixer
+    reads layer `layer` there and writes its new state back in place.
+    `chunk`: the time steps of one rematerialised chunk of a prompt's scan,
+    which bounds what a differentiated call keeps.
+
+    Head h reads group h // (nh / G) of B and C. Everything but the in and
+    out projections (the reuse sites) lies under the scope `ssm_update`,
+    the state's read and in-place write included."""
     from repro.models.layers import _maybe_reuse_matmul
 
     b, s, d = x.shape
     di, st, nh = cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads
-    hd = cfg.ssm_head_dim
-
-    hin = apply_norm(p["norm"], x, cfg.norm_eps)
-    zxbcdt = _maybe_reuse_matmul(
-        f"{prefix}_in", hin, p["in_proj"], None, reuse_ctx
-    )
-    z, xc, bc, cc, dt = jnp.split(zxbcdt, [di, 2 * di, 2 * di + st, 2 * di + 2 * st], -1)
-
-    conv_in = jnp.concatenate([xc, bc, cc], axis=-1)
-    conv_out, conv_state = _causal_conv(conv_in, p["conv_w"], p["conv_b"], state["conv"])
-    conv_out = jax.nn.silu(conv_out.astype(jnp.float32)).astype(x.dtype)
-    xc, bc, cc = jnp.split(conv_out, [di, di + st], axis=-1)
-
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])     # [B, S, nh]
-    a = -jnp.exp(p["A_log"])                                         # [nh]
-    decay = jnp.exp(dt * a)                                          # [B, S, nh]
-
-    xh = xc.reshape(b, s, nh, hd).astype(jnp.float32)
-    bf = bc.astype(jnp.float32)
-    cf = cc.astype(jnp.float32)
+    g, hd = cfg.ssm_groups, cfg.ssm_head_dim
+    rep, gs = nh // g, g * st
 
     def step(h, ins):
-        x_t, b_t, c_t, dt_t, dec_t = ins  # [B,nh,hd], [B,st], [B,st], [B,nh], [B,nh]
-        dx = dt_t[..., None] * x_t                                  # [B, nh, hd]
-        h = dec_t[..., None, None] * h + dx[..., :, None] * b_t[:, None, None, :]
-        y = jnp.einsum("bhps,bs->bhp", h, c_t)                      # [B, nh, hd]
-        return h, y
+        # [B,nh,hd], [B,G,st], [B,G,st], [B,nh], [B,nh]
+        x_t, b_t, c_t, dt_t, dec_t = ins
+        hg = h.reshape(b, g, rep, hd, st)
+        dx = (dt_t[..., None] * x_t).reshape(b, g, rep, hd)
+        hg = (dec_t.reshape(b, g, rep)[..., None, None] * hg
+              + dx[..., None] * b_t[:, :, None, None, :])
+        y = jnp.einsum("bgrps,bgs->bgrp", hg, c_t)
+        return hg.reshape(b, nh, hd, st), y.reshape(b, nh, hd)
 
-    xs = (
-        jnp.moveaxis(xh, 1, 0),
-        jnp.moveaxis(bf, 1, 0),
-        jnp.moveaxis(cf, 1, 0),
-        jnp.moveaxis(dt, 1, 0),
-        jnp.moveaxis(decay, 1, 0),
-    )
-    h, ys = _chunked_scan(step, state["h"], xs, chunk=min(s, 256))
-    y = jnp.moveaxis(ys, 0, 1)                                       # [B, S, nh, hd]
-    y = y + p["D"][:, None] * xh
-    y = y.reshape(b, s, di).astype(x.dtype)
+    with jax.named_scope("mamba"):
+        with jax.named_scope("ssm_update"):
+            hin = apply_norm(p["norm"], x, cfg.norm_eps)
+        zxbcdt = _maybe_reuse_matmul(
+            f"{prefix}_in", hin, p["in_proj"], None, reuse_ctx
+        )
+        with jax.named_scope("ssm_update"):
+            stacks = state
+            if layer is not None:
+                state = jax.tree.map(lambda a: jax.lax.index_in_dim(
+                    a, layer, keepdims=False), stacks)
+            z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * gs], -1)
+            conv_out, conv_state = _causal_conv(
+                xbc, p["conv_w"], p["conv_b"], state["conv"])
+            conv_out = jax.nn.silu(conv_out)
+            xc, bc, cc = jnp.split(conv_out, [di, di + gs], axis=-1)
 
-    y = rms_norm(y, p["out_norm"]["scale"], cfg.norm_eps)
-    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    out = _maybe_reuse_matmul(f"{prefix}_out", y, p["out_proj"], None, reuse_ctx)
-    return out.astype(x.dtype), {"conv": conv_state, "h": h}
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # [B,S,nh]
+            a = -jnp.exp(p["A_log"])                                     # [nh]
+            decay = jnp.exp(dt * a)                                      # [B,S,nh]
+
+            xh = xc.reshape(b, s, nh, hd)
+            xs = (xh, bc.reshape(b, s, g, st), cc.reshape(b, s, g, st), dt,
+                  decay)
+            if s == 1:
+                h, y = step(state["h"], tuple(v[:, 0] for v in xs))
+                y = y[:, None]
+            else:
+                h, ys = _chunked_scan(
+                    step, state["h"], tuple(jnp.moveaxis(v, 1, 0) for v in xs),
+                    chunk=min(s, chunk))
+                y = jnp.moveaxis(ys, 0, 1)                           # [B,S,nh,hd]
+            y = y + p["D"][:, None] * xh
+            y = _gated_group_norm(y.reshape(b, s, di), z.astype(jnp.float32),
+                                 p["out_norm"]["scale"], g, cfg.norm_eps)
+            y = y.astype(x.dtype)
+            new = {"conv": conv_state, "h": h}
+            if layer is not None:
+                new = jax.tree.map(
+                    lambda a, v: jax.lax.dynamic_update_index_in_dim(
+                        a, v, layer, 0), stacks, new)
+        out = _maybe_reuse_matmul(f"{prefix}_out", y, p["out_proj"], None,
+                                  reuse_ctx)
+    return out.astype(x.dtype), new
 
 
 def init_mamba2_state(cfg: ModelConfig, batch: int) -> dict:
     di, st, nh = cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads
     return {
-        "conv": jnp.zeros((batch, MAMBA_CONV_K - 1, di + 2 * st), cfg.dtype),
+        "conv": jnp.zeros((batch, MAMBA_CONV_K - 1,
+                           di + 2 * cfg.ssm_groups * st), cfg.dtype),
         "h": jnp.zeros((batch, nh, cfg.ssm_head_dim, st), jnp.float32),
     }

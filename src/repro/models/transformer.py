@@ -11,6 +11,12 @@ repeating group of layers — and the model scans over stacked superblocks:
                       shared across its 9 applications, per the paper)
   hubert (encoder)    1 superblock = [bidirectional attn, mlp], no decode path
 
+A pattern stack (nemotron-h: `layer_pattern`, one letter a layer) is the
+exception: its layers differ by kind and follow no period, so each kind's
+parameters, decode state and reuse caches are stacked by kind, and the
+layers run in pattern order, each reading its kind's stacks at its index
+within the kind.
+
 Scanning keeps the lowered HLO O(1) in depth (the dry-run compiles one
 superblock body), and per-superblock state (KV caches, SSM states, reuse
 caches) is sliced by the same scan.
@@ -38,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import LAYER_KINDS, ModelConfig
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (
@@ -79,7 +85,9 @@ def init_params(cfg: ModelConfig, key) -> Params:
     def init_superblock(k):
         return _init_superblock(cfg, k)
 
-    if cfg.scan_layers:
+    if cfg.layer_pattern:
+        p["blocks"] = _init_pattern(cfg, keys[: cfg.n_layers])
+    elif cfg.scan_layers:
         p["blocks"] = jax.vmap(init_superblock)(keys[: cfg.n_superblocks])
     else:
         blocks = [init_superblock(k) for k in keys[: cfg.n_superblocks]]
@@ -95,6 +103,21 @@ def init_params(cfg: ModelConfig, key) -> Params:
     if not cfg.tie_embeddings or cfg.frontend == "audio":
         p["lm_head"] = _dense_init(keys[-4], (cfg.d_model, cfg.vocab), dtype=cfg.dtype)
     return p
+
+
+_KIND_INIT = {"M": ssm_mod.init_mamba2, "-": init_mlp, "*": init_attention}
+
+
+def _init_pattern(cfg: ModelConfig, keys) -> Params:
+    """{group: the kind's layers stacked} of a pattern stack, layer l drawn
+    from keys[l]."""
+    out = {}
+    for letter, group in LAYER_KINDS.items():
+        idx = [i for i, c in enumerate(cfg.layer_pattern) if c == letter]
+        if idx:
+            out[group] = jax.vmap(
+                functools.partial(_KIND_INIT[letter], cfg))(keys[np.array(idx)])
+    return out
 
 
 def _init_superblock(cfg: ModelConfig, key) -> Params:
@@ -143,7 +166,15 @@ def init_decode_state(cfg: ModelConfig, batch: int, cache_len: int) -> dict:
         return jax.tree.map(lambda x: jnp.broadcast_to(x, (n, *x.shape)).copy(), tree)
 
     state: dict[str, Any] = {"len": jnp.zeros((), jnp.int32)}
-    if cfg.ssm_kind == "rwkv6":
+    if cfg.layer_pattern:
+        state["blocks"] = {}
+        if cfg.kind_layers("M"):
+            state["blocks"]["mamba"] = stack(
+                cfg.kind_layers("M"), ssm_mod.init_mamba2_state(cfg, batch))
+        if cfg.kind_layers("*"):
+            state["blocks"]["attn"] = stack(cfg.kind_layers("*"),
+                                            kv(cache_len))
+    elif cfg.ssm_kind == "rwkv6":
         state["blocks"] = stack(nsb, ssm_mod.init_rwkv6_state(cfg, batch))
     elif cfg.ssm_kind == "mamba2":
         blocks = stack(
@@ -298,6 +329,88 @@ def _block_forward(
     return x, (new_kv if decode else {})
 
 
+def _pattern_layer(cfg: ModelConfig, letter: str, lp: Params, x, st, *,
+                   positions, kv_len, reuse_ctx, state_layer):
+    """One layer of a pattern stack: its mixer, with its own pre-norm, and
+    the residual. Returns (x, the layer's new state or None). A Mamba2
+    mixer given `state_layer` takes its kind's state stacks whole and
+    updates its layer's state in place."""
+    if letter == "M":
+        if st is None:
+            st = ssm_mod.init_mamba2_state(cfg, x.shape[0])
+        # one chunk for a serving prompt of up to 512 tokens: more chunks
+        # only save memory under differentiation, and in a prefill their
+        # boundary copies double the temporaries
+        h, st = ssm_mod.mamba2_forward(lp, cfg, x, st, reuse_ctx=reuse_ctx,
+                                       layer=state_layer, chunk=512)
+    elif letter == "-":
+        h = mlp_forward(lp, cfg, x, reuse_ctx=reuse_ctx)
+    else:
+        h, st = attention_forward(
+            lp, cfg, x, layer_window=None, positions=positions, kv_cache=st,
+            kv_len=kv_len, reuse_ctx=reuse_ctx)
+    return x + h, st
+
+
+def _put(stack, v, *, i: int):
+    """`stack` with `v` written in place at index i of its leading axis."""
+    return jax.lax.dynamic_update_index_in_dim(stack, v, i, 0)
+
+
+def _pattern_forward(cfg: ModelConfig, blocks: Params, states, x, *,
+                     positions, kv_len, stacks, reuse_engine, reuse_cache,
+                     train: bool):
+    """The layers of a pattern stack in order. Layer l of kind k is the
+    i-th of its kind: it reads its parameters, state and reuse-cache entries
+    at index i of k's stacks, and writes its state and entries back there.
+    That slicing and write-back lies under `layer_scan` outside `layer`, as
+    a scan's does, but for two operands read in place: each reuse site's
+    weight stays whole in `stacks` and reaches the site as a LayerWeight at
+    i, and a Mamba2 mixer updates its layer's SSM and conv state in the
+    stack itself. Both ways compile to the same instructions with no copy of
+    the state stack; but XLA names the fused recurrence after its root, the
+    state's write-back, so writing it inside the mixer keeps the recurrence
+    under `ssm_update`. Returns (x, states, rcache)."""
+    states = dict(states) if states is not None else None
+    rcache = dict(reuse_cache) if reuse_cache is not None else None
+    seen = dict.fromkeys(LAYER_KINDS, 0)
+    for letter in cfg.layer_pattern:
+        group, i = LAYER_KINDS[letter], seen[letter]
+        seen[letter] += 1
+        lp = _with_layer_weights(
+            {group: jax.tree.map(lambda a: a[i], blocks[group])}, stacks,
+            jnp.int32(i))[group]
+        held = states is not None and group in states
+        in_place = held and letter == "M"
+        st = None
+        if held:
+            st = states[group] if in_place else jax.tree.map(
+                lambda a: a[i], states[group])
+        sites = [n for n in rcache or ()
+                 if SITE_WEIGHTS.get(n, ("",))[0] == group]
+        rctx = None
+        if reuse_engine is not None and rcache is not None:
+            rctx = (reuse_engine,
+                    {n: jax.tree.map(lambda a: a[i], rcache[n])
+                     for n in sites}, {})
+        layer = functools.partial(_pattern_layer, cfg, letter,
+                                  positions=positions, kv_len=kv_len,
+                                  reuse_ctx=rctx,
+                                  state_layer=i if in_place else None)
+        if train and cfg.remat:
+            layer = jax.checkpoint(layer, prevent_cse=False)
+        with jax.named_scope("layer"):
+            x, st = layer(lp, x, st)
+        if held:
+            states[group] = st if in_place else jax.tree.map(
+                functools.partial(_put, i=i), states[group], st)
+        if rctx is not None:
+            for n in sites:
+                rcache[n] = jax.tree.map(functools.partial(_put, i=i),
+                                         rcache[n], rctx[1][n])
+    return x, states, rcache
+
+
 def _unstacked_local(cfg, bp, x, positions, reuse_ctx):
     """Training/prefill path for local layers (no KV state): scan over the
     stacked local blocks with no per-layer state."""
@@ -334,11 +447,12 @@ def _lift_site_weights(blocks: Params, reuse_cache: dict):
 
 
 def _with_layer_weights(bp: Params, stacks: dict, layer) -> Params:
-    """A layer's block parameters with each lifted stack put back as a
-    LayerWeight at its path."""
+    """A layer's block parameters with each lifted stack of a group they
+    hold put back as a LayerWeight at its path."""
     bp = dict(bp)
     for (group, name), stack in stacks.items():
-        bp[group] = {**bp[group], name: LayerWeight(stack, layer)}
+        if group in bp:
+            bp[group] = {**bp[group], name: LayerWeight(stack, layer)}
     return bp
 
 
@@ -403,6 +517,18 @@ def forward(
     layers = np.arange(cfg.n_superblocks, dtype=np.int32) if stacks else None
 
     stats: dict[str, Any] = {}
+    if cfg.layer_pattern:
+        with jax.named_scope("layer_scan"):
+            x, new_bstates, new_rcache = _pattern_forward(
+                cfg, blocks, bstates, x, positions=positions,
+                kv_len=decode_state["len"] if decode else None,
+                stacks=stacks, reuse_engine=reuse_engine,
+                reuse_cache=reuse_cache, train=not decode)
+            new_state = None
+            if decode:
+                new_state = {"len": decode_state["len"] + s,
+                             "blocks": new_bstates}
+        return x, new_state, new_rcache, stats
 
     def body(carry, xs):
         xx = carry

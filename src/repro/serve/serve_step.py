@@ -17,10 +17,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import LAYER_KINDS, ModelConfig
 from repro.core.engine import ReuseEngine
 from repro.core.policy import ReusePolicy
 from repro.models import forward, init_decode_state, output_logits
+from repro.models.layers import SITE_WEIGHTS
 
 
 def build_reuse_engine(
@@ -55,6 +56,17 @@ def build_reuse_engine(
             mode=mode,
         )
 
+    shapes = _site_shapes(cfg)
+    if cfg.layer_pattern:
+        # a pattern stack: each in-place site of a kind the pattern holds,
+        # registered with that kind's layer count
+        letter = {group: k for k, group in LAYER_KINDS.items()}
+        for name, (group, _) in SITE_WEIGHTS.items():
+            n = cfg.kind_layers(letter[group])
+            if n:
+                eng.register(name, *shapes[name], n_layers=n,
+                             block_m=block_m, block_k=block_k)
+        return eng
     if cfg.ssm_kind == "rwkv6":
         for nm in ("wr", "wk", "wv", "wg"):
             reg(f"rwkv_{nm}", d, d)
@@ -64,7 +76,8 @@ def build_reuse_engine(
         reg("rwkv_cmix_wr", d, d)
         return eng
     if cfg.ssm_kind == "mamba2":
-        # inner mamba sites are nested (excluded); the shared block carries reuse
+        # zamba2's inner mamba sites are nested (excluded); the shared block
+        # carries reuse
         if cfg.hybrid_attn_every:
             reg("shared_attn_qkv", d, cfg.q_dim + 2 * cfg.kv_dim)
             reg("shared_attn_out", cfg.q_dim, d)
@@ -81,17 +94,30 @@ def build_reuse_engine(
         reg("mlp_global_out", cfg.d_ff, d)
         return eng
 
-    reg("attn_qkv", d, cfg.q_dim + 2 * cfg.kv_dim)
-    reg("attn_out", cfg.q_dim, d)
+    reg("attn_qkv", *shapes["attn_qkv"])
+    reg("attn_out", *shapes["attn_out"])
     if cfg.n_experts:
         if cfg.shared_expert:
             reg("moe_shared_in", d, 2 * cfg.d_ff)
             reg("moe_shared_out", cfg.d_ff, d)
     else:
-        fi = 2 * cfg.d_ff if cfg.mlp_kind == "swiglu" else cfg.d_ff
-        reg("mlp_in", d, fi)
-        reg("mlp_out", cfg.d_ff, d)
+        reg("mlp_in", *shapes["mlp_in"])
+        reg("mlp_out", *shapes["mlp_out"])
     return eng
+
+
+def _site_shapes(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
+    """(fan-in, fan-out) of each site of `layers.SITE_WEIGHTS`."""
+    d, di, f = cfg.d_model, cfg.d_inner, cfg.d_ff
+    return {
+        "attn_qkv": (d, cfg.q_dim + 2 * cfg.kv_dim),
+        "attn_out": (cfg.q_dim, d),
+        "mlp_in": (d, 2 * f if cfg.mlp_kind == "swiglu" else f),
+        "mlp_out": (f, d),
+        "mamba_in": (d, 2 * di + 2 * cfg.ssm_groups * cfg.ssm_state
+                     + cfg.n_ssm_heads),
+        "mamba_out": (di, d),
+    }
 
 
 def prefill_step(

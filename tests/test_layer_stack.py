@@ -82,7 +82,7 @@ def test_aligned_stack_is_not_padded():
 # ---------------------------------------------------------------- decode step
 
 BATCH, CACHE_LEN, STEPS = 8, 16, 3
-ARCHS = ("qwen3-32b", "nemotron-4-15b")
+ARCHS = ("qwen3-32b", "nemotron-4-15b", "nemotron-h-47b")
 
 
 def _decode(cfg, engine, params, *, in_place: bool, monkeypatch):
@@ -124,7 +124,7 @@ def _check_decode(cfg, engine, monkeypatch):
     params = init_params(cfg, jax.random.PRNGKey(0))
     _, stacks = transformer._lift_site_weights(
         params["blocks"], engine.init_cache(BATCH))
-    assert len(stacks) == 4
+    assert len(stacks) == len(engine.sites)
     _assert_bitwise(
         _decode(cfg, engine, params, in_place=True, monkeypatch=monkeypatch),
         _decode(cfg, engine, params, in_place=False, monkeypatch=monkeypatch))

@@ -29,8 +29,9 @@ from repro.serve.serve_step import (
 PARTS = ("embed", "layer_scan", "head")
 BATCH, CACHE_LEN, PROMPT = 8, 48, 16
 # qwen3: SwiGLU, qk-norm, tied head when reduced; nemotron: squared-ReLU
-# without gate, untied head
-ARCHS = ("qwen3-32b", "nemotron-4-15b")
+# without gate, untied head; nemotron-h: a pattern stack of Mamba2, MLP and
+# attention layers, unrolled in pattern order
+ARCHS = ("qwen3-32b", "nemotron-4-15b", "nemotron-h-47b")
 STEPS = ("decode", "prefill")
 # what the scan itself lowers to, below `layer_scan`: the loop, its counter
 # and condition, the per-layer slices and write-backs of its operands, the
@@ -39,6 +40,16 @@ STEPS = ("decode", "prefill")
 SCAN_OWN = re.compile(
     r"^(while(/cond/\w+|/body/(squeeze|dynamic_slice|dynamic_update_slice"
     r"|add|closed_call))?|broadcast_in_dim|add)$")
+# a pattern stack runs its layers unrolled, outside any loop: besides the
+# above, its static per-layer slices and in-place write-backs of its kinds'
+# stacks
+PATTERN_OWN = re.compile(
+    r"^(while(/cond/\w+|/body/(squeeze|dynamic_slice|dynamic_update_slice"
+    r"|add|closed_call))?|broadcast_in_dim|add|slice|dynamic_update_slice)$")
+# the ops through which the layer's weights reach it: the loop's slices, or
+# a pattern stack's static ones
+WEIGHT_SLICES = ("squeeze", "dynamic_slice")
+PATTERN_WEIGHT_SLICES = ("squeeze", "dynamic_slice", "slice")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
@@ -71,8 +82,9 @@ def hlo():
 def _traced(text: str) -> list[list[str]]:
     """The scope segments below the step's own `jit(...)` of every
     instruction the step traced; instructions XLA adds itself carry no
-    `jit(` op_name."""
-    names = (m.group(1) for m in _OP_NAME.finditer(text))
+    `jit(` op_name, and one that XLA made of several carries their names
+    joined by `;`."""
+    names = (n for m in _OP_NAME.finditer(text) for n in m.group(1).split(";"))
     return [n.split("/")[1:] for n in names if n.startswith("jit(")]
 
 
@@ -106,10 +118,13 @@ def test_scan_ops_are_layers_or_the_scans_own(hlo, arch, step):
             if segs[0] == "layer_scan"]
     slices = [s for s in scan if "layer" not in s]
     assert len(slices) < len(scan)
+    pattern = bool(get_config(arch).layer_pattern)
+    own = PATTERN_OWN if pattern else SCAN_OWN
     for segs in slices:
-        assert SCAN_OWN.match("/".join(segs)), segs
+        assert own.match("/".join(segs)), segs
     # the layer's weights reach it through the scan's slices
-    assert any(s[-1] in ("squeeze", "dynamic_slice") for s in slices)
+    weight_slices = PATTERN_WEIGHT_SLICES if pattern else WEIGHT_SLICES
+    assert any(s[-1] in weight_slices for s in slices)
 
 
 @cases

@@ -208,3 +208,46 @@ def test_decode_step_reads_site_weights_in_place(one_chip, monkeypatch, case):
     for kn, line in made:
         assert (f"/layer/reuse_site:{aligned[kn]}/cond/branch_0_fun/"
                 in _OP_NAME.search(line).group(1)), line
+
+
+def test_pattern_decode_step_reads_each_kinds_stack_in_place(one_chip,
+                                                            monkeypatch):
+    """A pattern stack's GEMM kernels take each kind's stacked weights
+    `[L_kind, K, N]` themselves, and its Mamba2 mixers write their SSM
+    state in place in the kind's state stack, under `ssm_update`."""
+    cfg = dataclasses.replace(
+        get_config("nemotron-h-47b").reduced(), d_model=256, n_heads=2,
+        n_kv_heads=1, head_dim=128, d_ff=1024, vocab=384, ssm_head_dim=64,
+        ssm_state=30, param_dtype="bfloat16")
+    monkeypatch.setattr(backend, "best", lambda: backend.PALLAS)
+    engine = build_reuse_engine(cfg, impl="pallas")
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), jax.eval_shape(tree))
+
+    args = (shapes(lambda: init_params(cfg, jax.random.PRNGKey(0))),
+            jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip),
+            shapes(lambda: init_serve_state(cfg, 8, 64)),
+            shapes(lambda: engine.init_cache(8)))
+    hlo = jit_decode(cfg, engine).lower(*args).compile().as_text()
+    calls = cfg.n_layers * 2
+    assert pallas_kernel_calls(hlo) == {"delta_quant": calls,
+                                        "reuse_matmul_output": calls}
+    weights = [re.findall(r"bf16\[([\d,]+)\]",
+                          _OPERANDS.search(line).group(1))[1]
+               for line in hlo.splitlines()
+               if "tpu_custom_call" in line and _GEMM_CALL.search(line)]
+    want = []
+    for name, s in engine.sites.items():
+        assert s.in_features % s.block_k == 0 == s.out_features % s.block_n
+        n = engine.stacking[name]
+        want += [f"{n},{s.in_features},{s.out_features}"] * n
+    assert sorted(weights) == sorted(want)
+    h = "f32[2,8,{},64,30]".format(cfg.n_ssm_heads)
+    writes = [_OP_NAME.search(line).group(1) for line in hlo.splitlines()
+              if line.lstrip().startswith(("%", "ROOT"))
+              and f" = {h}" in line and "dynamic-update-slice(" in line]
+    assert writes
+    for name in writes:
+        assert "/layer/mamba/ssm_update/" in name, name
